@@ -33,6 +33,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MaRe
 from repro.io import fasta_source
 
@@ -152,6 +153,7 @@ def follow(epochs: int = 4, bases_per_epoch: int = 10_000):
 
 
 def main():
+    enable_compile_cache()
     tmp = tempfile.mkdtemp(prefix="mare_kmer_")
     fasta = os.path.join(tmp, "genome.fa")
     lines = write_genome(fasta)

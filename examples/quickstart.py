@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MaRe, PlanTypeError, TextFile, DEFAULT_CACHE
 from repro.io import fasta_source
 
@@ -33,6 +34,7 @@ def write_genome(path: str, n_bases: int = 100_000, seed: int = 42) -> str:
 
 
 def main():
+    enable_compile_cache()
     tmp = tempfile.mkdtemp(prefix="mare_quickstart_")
     fasta = os.path.join(tmp, "genome.fa")
     seq = write_genome(fasta)

@@ -12,9 +12,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np
 
 from benchmarks.apps import make_library, snp_calling
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     reads = make_library(8_192, seed=3)
     chrom, score, read_id = snp_calling(reads)
     n = len(np.asarray(read_id))

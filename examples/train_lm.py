@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.data import Prefetcher, SyntheticText, lm_batches
 from repro.models import build_model, param_count
@@ -28,6 +29,7 @@ from repro.train import (StepConfig, Trainer, TrainerConfig,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -16,9 +16,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np
 
 from benchmarks.apps import make_library, virtual_screening, vs_reference
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     library = make_library(20_000, seed=7)
     scores, mol_ids = virtual_screening(library, top=30)
     ref_scores, ref_ids = vs_reference(library, top=30)

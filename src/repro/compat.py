@@ -1,69 +1,34 @@
-"""Version-tolerant JAX API surface.
+"""Mesh and shard_map construction in one place.
 
-The repo targets the modern spellings (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``,
-``check_vma=``) but must also run on 0.4.x installations where
-``shard_map`` still lives in ``jax.experimental``, meshes take no
-``axis_types``, and the replication-check kwarg is ``check_rep``.
-All mesh/shard_map construction in this repo goes through here.
+Every mesh in the repo is built with Auto axis types and every
+``shard_map`` goes through :func:`shard_map`, whose ``check_vma=None``
+keeps JAX's default (the planner turns the check off for programs that
+may hold a ``pallas_call``, which has no replication rule).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional, Sequence
 
 import jax
 
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # jax < 0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = set(inspect.signature(_shard_map_impl).parameters)
-_MAKE_MESH_PARAMS = (set(inspect.signature(jax.make_mesh).parameters)
-                     if hasattr(jax, "make_mesh") else None)
+AxisType = jax.sharding.AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               devices: Optional[Sequence[Any]] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported; builds the
-    Mesh directly on JAX versions predating ``jax.make_mesh``."""
-    shape = tuple(axis_shapes)
+    """``jax.make_mesh`` with Auto axis types."""
     names = tuple(axis_names)
-    if _MAKE_MESH_PARAMS is None:
-        import numpy as np
-        n = int(np.prod(shape))
-        devs = list(devices) if devices is not None else jax.devices()[:n]
-        return jax.sharding.Mesh(np.asarray(devs).reshape(shape), names)
-    kwargs: dict = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AxisType is not None and "axis_types" in _MAKE_MESH_PARAMS:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(names)
-    return jax.make_mesh(shape, names, **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg translated to
-    whatever this installation calls it (``check_vma`` vs ``check_rep``)."""
+    """``jax.shard_map``; ``check_vma=None`` leaves JAX's default."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to a flat dict (older JAX
-    returns a one-element list of per-computation dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-__all__ = ["AxisType", "make_mesh", "shard_map", "cost_analysis"]
+__all__ = ["AxisType", "make_mesh", "shard_map"]

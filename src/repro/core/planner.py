@@ -56,19 +56,19 @@ class CompiledProgram:
     #: by :meth:`ensure_compiled` when tracing is enabled.
     cost: Optional[Dict[str, float]] = None
     _aot: Optional[Callable[..., Tuple]] = None   # jax.stages.Compiled
-    _aot_failed: bool = False
 
     def __call__(self, records: Any, counts: jax.Array) -> Tuple:
         if self._aot is not None:
-            try:
-                return self._aot(records, counts)
-            except Exception:
-                # e.g. an argument placed differently than the arrays the
-                # program was AOT-compiled against; programs are pure, so
-                # falling back to the lazy jit path re-runs safely
-                self._aot = None
-                self._aot_failed = True
+            return self._aot(records, counts)
         return self.fn(records, counts)
+
+    def as_text(self) -> str:
+        """The compiled program's HLO text (after :meth:`ensure_compiled`)
+        — e.g. to check that a Pallas kernel lowered to a
+        ``tpu_custom_call``."""
+        if self._aot is None:
+            raise RuntimeError("program not compiled yet")
+        return self._aot.as_text()
 
     @property
     def num_counters(self) -> int:
@@ -82,27 +82,22 @@ class CompiledProgram:
 
         The compiled executable is reused for every later dispatch (the
         plan cache keys on shapes/dtypes/mesh, so one signature per
-        program).  Any AOT failure — e.g. an API gap on an old JAX —
-        falls back permanently to the lazy ``jax.jit`` path, whose
-        compile time then lands in the ``dispatch`` phase.
+        program).  Lowering and compile errors propagate: a program the
+        backend refuses is a fault, not a reason to re-jit lazily.
         """
-        if self._aot is not None or self._aot_failed:
+        if self._aot is not None:
             return
-        try:
-            with timed("plan.lower", phases):
-                lowered = self.fn.lower(records, counts)
-            with timed("plan.compile", phases) as sp:
-                compiled = lowered.compile()
-                if TRACER.enabled:
-                    # annotate the compile span with what the compiled
-                    # program *does* per dispatch, not just how long the
-                    # compile took
-                    self.cost = _estimate_cost(compiled)
-                    if self.cost:
-                        sp.set(**self.cost)
-        except Exception:
-            self._aot_failed = True
-            return
+        with timed("plan.lower", phases):
+            lowered = self.fn.lower(records, counts)
+        with timed("plan.compile", phases) as sp:
+            compiled = lowered.compile()
+            if TRACER.enabled:
+                # annotate the compile span with what the compiled
+                # program *does* per dispatch, not just how long the
+                # compile took
+                self.cost = _estimate_cost(compiled)
+                if self.cost:
+                    sp.set(**self.cost)
         self._aot = compiled
 
 
@@ -145,6 +140,10 @@ class PlanCache:
     def stats(self) -> Dict[str, int]:
         return {"programs": len(self._programs), "hits": self.hits,
                 "misses": self.misses}
+
+    def programs(self) -> List[CompiledProgram]:
+        """Retained programs, least recently used first."""
+        return list(self._programs.values())
 
     def clear(self) -> None:
         self._programs.clear()
@@ -356,17 +355,11 @@ def _plan_uses_pallas(plan: Plan) -> bool:
     kernel (shard_map has no replication rule for pallas_call, so such a
     program must be built with the replication check off).  Conservative:
     with ``use_kernel=None`` the autotuner decides at trace time, so this
-    answers "is tiled in the candidate set" (TPU backend, env force, or
-    ``REPRO_SEGMENT_TUNE_PALLAS=1``), not "will tiled win"."""
-    import os
-
+    answers "is tiled in the candidate set" (TPU backend or a forced
+    kernel), not "will tiled win"."""
     from repro.kernels.segment_reduce.ops import resolve_use_kernel
-    tuner_may_pick = (jax.default_backend() == "tpu"
-                      or os.environ.get("REPRO_SEGMENT_TUNE_PALLAS") == "1")
     return any(isinstance(st, KeyedReduceStage)
-               and (resolve_use_kernel(st.use_kernel, st.op)
-                    or (st.use_kernel is None and st.op == "sum"
-                        and tuner_may_pick))
+               and resolve_use_kernel(st.use_kernel, st.op)
                for st in plan.stages)
 
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 NEG_INF = -1e30  # avoid NaNs from (-inf) - (-inf) in fully-masked rows
 
@@ -128,7 +128,7 @@ def flash_attention_kernel(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 
 def _dispatch_kernel(assign_ref, pos_ref, counts_out_ref, counts_ref, *,
@@ -68,7 +68,7 @@ def moe_dispatch_kernel(assignments: jnp.ndarray, num_groups: int,
             jax.ShapeDtypeStruct((num_groups,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((num_groups,), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(assignments.astype(jnp.int32))

@@ -12,8 +12,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, d: int):
@@ -41,7 +42,7 @@ def rmsnorm_kernel(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, weight)

@@ -48,8 +48,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import use_interpret
-from repro.kernels.segment_reduce.kernel import (segment_sum_kernel,
-                                                segment_sum_tiled)
+from repro.kernels.segment_reduce.kernel import (DEFAULT_BLOCK,
+                                                DEFAULT_KEY_BLOCK,
+                                                segment_sum_tiled, tiling)
 from repro.kernels.segment_reduce.ref import (MONOIDS, SegmentReduceResult,
                                               monoid_identity,
                                               segment_reduce_fused,
@@ -118,22 +119,8 @@ def segment_reduce_impl(keys: jnp.ndarray, values: Any, num_keys: int,
     if strategy == "sorted":
         return segment_reduce_sorted(keys, values, num_keys, valid=valid)
     if strategy == "tiled":
-        leaves, treedef = jax.tree.flatten(values)
-        tables = []
-        counts = overflow = None
-        for leaf in leaves:
-            tail = leaf.shape[1:]
-            flat = leaf.reshape(leaf.shape[0], -1) if leaf.ndim != 2 else leaf
-            tab, cnt, ovf = segment_sum_tiled(keys, flat, num_keys, valid,
-                                              block=block,
-                                              key_block=key_block,
-                                              interpret=interpret)
-            tables.append(tab.reshape((num_keys,) + tail))
-            if counts is None:
-                counts, overflow = cnt, ovf[0]
-        return SegmentReduceResult(
-            values=jax.tree.unflatten(treedef, tables),
-            counts=counts, overflow=overflow)
+        return segment_sum_tiled(keys, values, num_keys, valid, block=block,
+                                 key_block=key_block, interpret=interpret)
     return segment_reduce_ref(keys, values, num_keys, op=op, valid=valid)
 
 
@@ -142,7 +129,7 @@ def segment_reduce(keys: jnp.ndarray, values: Any, num_keys: int,
                    valid: Optional[jnp.ndarray] = None,
                    use_kernel: Optional[bool] = None,
                    strategy: Optional[str] = None,
-                   block: int = 512,
+                   block: Optional[int] = None,
                    key_block: Optional[int] = None,
                    interpret: Optional[bool] = None) -> SegmentReduceResult:
     """Aggregate ``values`` ([n, ...] pytree) per key into a
@@ -163,10 +150,10 @@ def segment_reduce(keys: jnp.ndarray, values: Any, num_keys: int,
         autotunes (see module docstring for the env overrides).
       strategy: explicit strategy name overriding ``use_kernel``
         entirely; one of ``STRATEGIES``.
-      block: record-block length for the tiled kernel grid.
+      block: record-block length for the tiled kernel grid; ``None``
+        takes the tuned or default tiling.
       key_block: key-table tile height for the tiled kernel; ``None``
-        keeps the whole table resident (clamped to VMEM-safe sizes by
-        the autotuner when it picks the tiling itself).
+        takes the tuned or default tiling.
       interpret: force/forbid Pallas interpret mode; ``None`` follows
         :func:`use_interpret` (interpret everywhere but real TPU).
 
@@ -180,9 +167,8 @@ def segment_reduce(keys: jnp.ndarray, values: Any, num_keys: int,
     strat, tuned_block, tuned_kb = resolve_strategy(
         use_kernel, op, n, num_keys, values, strategy=strategy)
     if strat == "tiled":
-        if tuned_block:
-            block = tuned_block
-        kb = key_block if key_block is not None else (tuned_kb or num_keys)
+        block, kb = tiling(n, num_keys, block or tuned_block or DEFAULT_BLOCK,
+                           key_block or tuned_kb or DEFAULT_KEY_BLOCK)
     else:
         kb = 0
         block = 0
@@ -196,4 +182,4 @@ __all__ = ["segment_reduce", "segment_reduce_impl", "segment_reduce_ref",
            "segment_reduce_fused", "segment_reduce_sorted",
            "resolve_use_kernel", "resolve_strategy", "STRATEGIES",
            "SegmentReduceResult", "MONOIDS", "monoid_identity",
-           "segment_sum_kernel", "segment_sum_tiled"]
+           "segment_sum_tiled"]

@@ -9,11 +9,11 @@ times a few warm repetitions each, and caches the winner per
 
 where the leaf signature is the tuple of ``(trailing shape, dtype)`` per
 value leaf.  Tuning happens *at trace time* — candidate impls are jit'd
-and executed on concrete arrays while the caller's trace is suspended,
-which jax supports because ``jax.jit`` on fresh concrete inputs opens an
-independent trace.  The cost is a few milliseconds per distinct shape,
-paid once per process and amortized by the plan cache (a cached compiled
-program never re-traces, so it never re-tunes).
+and executed on concrete arrays under ``jax.ensure_compile_time_eval()``,
+which runs them eagerly instead of staging them into the caller's trace.  The cost
+grows with the shape (compile plus four runs per candidate), is paid
+once per process and shape, and is amortized by the plan cache (a cached
+compiled program never re-traces, so it never re-tunes).
 
 Candidate set (see docs/kernels.md for the measured numbers):
 
@@ -22,20 +22,22 @@ Candidate set (see docs/kernels.md for the measured numbers):
   (the CPU winner: XLA CPU pays per scatter op, not per column).
 * ``sorted``  — :func:`segment_reduce_sorted`, argsort + cumsum + diff
   (integer leaves only; exact by wraparound cancellation).
-* ``tiled[b,kb]`` — the Pallas kernel of ``kernel.py`` over a small grid
-  of ``(block, key_block)`` tilings, filtered by the VMEM budget.  Only
-  offered on TPU: in interpret mode (CPU) each grid step costs ~30ms of
-  pure Python, so it can never win — set ``REPRO_SEGMENT_TUNE_PALLAS=1``
-  to force it into the candidate set anyway (tests do, to exercise the
-  plumbing).
+* ``tiled[b,kb]`` — the Pallas kernel of ``kernel.py`` over the
+  ``TILINGS`` ladder.  Offered on TPU only (in interpret mode each grid
+  step is Python, so it can never win), and only while its dense one-hot
+  work ``n * num_keys`` stays within :data:`MAX_DENSE_CELLS`: beyond
+  that one candidate would run for minutes at trace time (a k=12 k-mer
+  table is ~1.5e8 records x 1.7e7 keys).  Every tiling fits the
+  compiler's scoped VMEM limit (``tests/test_chip_compile.py`` compiles
+  each one for a v5e).
 
-Environment knobs:
+A candidate that raises fails the tune: a strategy that cannot run is a
+fault to fix, not a timing to skip.
 
-* ``REPRO_SEGMENT_AUTOTUNE=0`` — skip measurement; return the static
-  heuristic (``tiled`` on TPU, ``fused`` elsewhere) without running
-  candidates.  Useful when trace determinism matters more than the last
-  2x.
-* ``REPRO_SEGMENT_TUNE_PALLAS=1`` — include Pallas tilings off-TPU.
+``REPRO_SEGMENT_AUTOTUNE=0`` skips measurement and returns the static
+heuristic (:func:`_default_strategy`, under the same dense-work bound).
+At the smoke's k=12 size on a v5e, tuning takes ~5 minutes of trace time
+(three candidates at 1.6e8 records, compiled and run four times each).
 
 :func:`tune_report` exposes everything tried this process (chosen
 strategy, per-candidate timings) — ``benchmarks/kmer.py`` embeds it in
@@ -52,13 +54,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import VMEM_BYTES, use_interpret
+from repro.kernels.common import VMEM_LIMIT_BYTES, use_interpret
+from repro.kernels.segment_reduce.kernel import (DEFAULT_BLOCK,
+                                                DEFAULT_KEY_BLOCK, tiling,
+                                                vmem_bytes)
 
 #: strategies a Strategy.name may take (``tiled`` carries block params too)
 STRATEGIES = ("scatter", "fused", "sorted", "tiled")
 
 #: (block, key_block) tilings the tuner tries for the Pallas kernel
-TILINGS = ((256, 512), (512, 1024), (512, 4096), (1024, 2048))
+TILINGS = ((1024, 1024), (1024, 4096), (2048, 4096), (2048, 8192))
+
+#: Most one-hot cells (records x keys) a tiled candidate may cost.  At the
+#: MXU rate of a v5e (~1e12 cells/s for this kernel's shape) 2**40 cells
+#: is about a second; the kernel does this work for any key order.
+MAX_DENSE_CELLS = 1 << 40
 
 _WARMUP = 1
 _REPS = 3
@@ -84,25 +94,34 @@ def _all_int_leaves(leaf_sig: Tuple) -> bool:
                for _, name in leaf_sig)
 
 
-def _vmem_fits(block: int, key_block: int, d: int, itemsize: int) -> bool:
-    """Rough VMEM residency of one grid step of the tiled kernel."""
-    table = key_block * max(d, 1) * itemsize        # resident tile (x2: out)
-    counts = key_block * 4
-    one_hot = block * key_block * itemsize          # intermediate
-    records = block * max(d, 1) * itemsize
-    return 2 * table + 2 * counts + one_hot + records <= VMEM_BYTES // 2
+def _columns(leaf_sig: Tuple) -> int:
+    return sum(int(np.prod(shape)) for shape, _ in leaf_sig)
+
+
+def tiled_feasible(n: int, num_keys: int) -> bool:
+    """Whether the tiled kernel's dense one-hot work is within bounds."""
+    return n * num_keys <= MAX_DENSE_CELLS
+
+
+def _vmem_fits(block: int, key_block: int, ncols: int) -> bool:
+    return vmem_bytes(block, key_block, ncols) <= VMEM_LIMIT_BYTES
+
+
+@jax.jit
+def _stride_keys(idx: jax.Array, num_keys: jax.Array) -> jax.Array:
+    return ((idx * jnp.uint32(2654435761)) % num_keys).astype(jnp.int32)
 
 
 def _synthetic(n: int, num_keys: int, leaf_sig: Tuple):
-    """Concrete sample problem matching the traced shapes.
+    """Concrete sample problem matching the traced shapes, made on the
+    device.
 
     Keys are a fixed permutation-ish pattern (golden-ratio stride) so every
     strategy sees realistic scatter conflicts; no RNG, so tuning is
     deterministic per shape.
     """
-    idx = np.arange(max(n, 1), dtype=np.uint64)
-    keys = ((idx * np.uint64(2654435761)) % np.uint64(max(num_keys, 1)))
-    keys = jnp.asarray(keys.astype(np.int32))
+    keys = _stride_keys(jnp.arange(n, dtype=jnp.uint32),
+                        jnp.uint32(max(num_keys, 1)))
     leaves = [jnp.ones((n,) + shape, np.dtype(name))
               for shape, name in leaf_sig]
     valid = jnp.ones((n,), bool)
@@ -124,32 +143,26 @@ def _candidates(backend: str, op: str, n: int, num_keys: int,
     cands: List[Tuple[str, int, int]] = [("fused", 0, 0), ("scatter", 0, 0)]
     if _all_int_leaves(leaf_sig):
         cands.append(("sorted", 0, 0))
-    want_pallas = (backend == "tpu"
-                   or os.environ.get("REPRO_SEGMENT_TUNE_PALLAS") == "1")
-    if want_pallas:
+    if backend == "tpu" and tiled_feasible(n, num_keys):
         for block, key_block in TILINGS:
-            d = 1
-            itemsize = 4
-            for shape, name in leaf_sig:
-                d = max(d, int(np.prod(shape)) if shape else 1)
-                itemsize = max(itemsize, np.dtype(name).itemsize)
-            if _vmem_fits(block, key_block, d, itemsize):
-                cands.append(("tiled", min(block, max(8, n)),
-                              min(key_block, num_keys)))
-    # dedupe clamped tilings
-    seen = set()
-    uniq = []
-    for c in cands:
-        if c not in seen:
-            seen.add(c)
-            uniq.append(c)
-    return uniq
+            if _vmem_fits(block, key_block, _columns(leaf_sig)):
+                cands.append(("tiled",) + tiling(n, num_keys, block,
+                                                 key_block))
+    return list(dict.fromkeys(cands))       # tilings may clamp alike
 
 
-def _default_strategy(backend: str) -> Tuple[str, int, int]:
-    if backend == "tpu":
-        return ("tiled", 512, 1024)
-    return ("fused", 0, 0)
+def _default_strategy(backend: str, n: int,
+                      num_keys: int) -> Tuple[str, int, int]:
+    """The untimed pick.  On TPU: the tiled kernel where its dense work is
+    bounded, else the plain scatter (the tuner's pick for a k=12 k-mer
+    table on a v5e, 3.3x faster than fused and 3.8x than sorted at 1.6e8
+    records).  Elsewhere the fused scatter."""
+    if backend != "tpu":
+        return ("fused", 0, 0)
+    if tiled_feasible(n, num_keys):
+        return ("tiled",) + tiling(n, num_keys, DEFAULT_BLOCK,
+                                   DEFAULT_KEY_BLOCK)
+    return ("scatter", 0, 0)
 
 
 def pick_strategy(op: str, n: int, num_keys: int, values: Any,
@@ -170,11 +183,7 @@ def pick_strategy(op: str, n: int, num_keys: int, values: Any,
     if hit is not None:
         return hit
     if os.environ.get("REPRO_SEGMENT_AUTOTUNE") == "0" or n == 0:
-        choice = _default_strategy(backend) if backend == "tpu" \
-            else ("fused", 0, 0)
-        if choice[0] == "tiled":
-            choice = ("tiled", min(choice[1], max(8, n)),
-                      min(choice[2], num_keys))
+        choice = _default_strategy(backend, n, num_keys)
         _CACHE[key] = choice
         return choice
     choice = _measure(key, op, n, num_keys, leaf_sig, backend)
@@ -187,12 +196,15 @@ def _measure(key: Tuple, op: str, n: int, num_keys: int, leaf_sig: Tuple,
     from repro.kernels.segment_reduce import ops as _ops
     from repro.obs import TRACER
 
-    keys, leaves, valid = _synthetic(n, num_keys, leaf_sig)
-    values = tuple(leaves)
     rows: List[Dict[str, Any]] = []
     best: Optional[Tuple[float, Tuple[str, int, int]]] = None
-    with TRACER.span("segment_reduce.autotune",
-                     n=n, num_keys=num_keys, backend=backend):
+    # the tuner runs while the caller's program is being traced; without
+    # eval context the candidates would be staged into that trace and
+    # the clock would time tracing, not execution
+    with TRACER.span("segment_reduce.autotune", n=n, num_keys=num_keys,
+                     backend=backend), jax.ensure_compile_time_eval():
+        keys, leaves, valid = _synthetic(n, num_keys, leaf_sig)
+        values = tuple(leaves)
         for strat, block, key_block in _candidates(backend, op, n, num_keys,
                                                    leaf_sig):
             def run(k, v, m, _s=strat, _b=block, _kb=key_block):
@@ -200,16 +212,13 @@ def _measure(key: Tuple, op: str, n: int, num_keys: int, leaf_sig: Tuple,
                     k, v, num_keys, op=op, valid=m, strategy=_s,
                     block=_b, key_block=_kb,
                     interpret=use_interpret())
-            try:
-                dt = _time_callable(run, keys, values, valid)
-            except Exception:        # a candidate failing must not poison tune
-                continue
+            dt = _time_callable(run, keys, values, valid)
             label = (f"tiled[{block},{key_block}]" if strat == "tiled"
                      else strat)
-            rows.append({"candidate": label, "ms": round(dt * 1e3, 4)})
+            rows.append({"candidate": label, "ms": dt * 1e3})
             if best is None or dt < best[0]:
                 best = (dt, (strat, block, key_block))
-    choice = best[1] if best else ("scatter", 0, 0)
+    choice = best[1]
     _REPORT.append({
         "backend": backend, "op": op, "n": n, "num_keys": num_keys,
         "leaves": [list(map(str, sig)) for sig in leaf_sig],

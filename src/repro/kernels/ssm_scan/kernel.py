@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 
 def _ssm_kernel(xc_ref, xproj_ref, dtb_ref, alog_ref, h0_ref,
@@ -100,7 +100,7 @@ def ssm_scan_kernel(xc: jnp.ndarray, x_proj: jnp.ndarray,
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xc, x_proj, dt_bias, a_log, h0)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 NEG_INF = -1e30
 
@@ -99,7 +99,7 @@ def topk_reduce_kernel(scores: jnp.ndarray, k: int,
             pltpu.VMEM((k,), jnp.float32),
             pltpu.VMEM((k,), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(scores, count)

@@ -19,7 +19,6 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -293,7 +292,7 @@ def dry_run_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     t_compile = time.monotonic() - t0 - t_lower
 
     # XLA's own cost_analysis (trip-count-blind; kept as cross-check)
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     try:

@@ -28,6 +28,8 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 
 # -- the legacy token-decode smoke -------------------------------------------
 
@@ -212,6 +214,7 @@ def main(argv=None) -> int:
     ap.add_argument("--greedy", action="store_true", default=True)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.model_smoke:
         return _model_smoke(args)
     return _service_loop(args)

@@ -32,8 +32,7 @@ def test_scan_trip_count_multiplies():
 
     fs = analyze(jax.jit(scanned).lower(a).compile().as_text())["flops"]
     fu = analyze(jax.jit(unrolled).lower(a).compile().as_text())["flops"]
-    from repro.compat import cost_analysis
-    xla = cost_analysis(jax.jit(scanned).lower(a).compile())["flops"]
+    xla = jax.jit(scanned).lower(a).compile().cost_analysis()["flops"]
     assert abs(fs - fu) / fu < 0.02
     assert xla < fs / 5          # demonstrates the undercount being fixed
 

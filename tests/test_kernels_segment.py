@@ -59,6 +59,37 @@ def test_segment_sum_kernel_matches_ref_int32():
     assert int(ker.overflow) == int(ref.overflow)
 
 
+@pytest.mark.parametrize("dtype", [np.int32, np.uint8, np.float32])
+def test_tiled_matches_scatter_bit_for_bit(dtype):
+    """Full-range int32 sums wrap exactly as the scatter oracle's do (the
+    kernel sums bytes on the MXU and recombines them); narrow ints wrap
+    in their own width; a NaN in a masked float slot never leaks."""
+    n, num_keys = 3000, 300
+    keys = RNG.integers(-2, num_keys + 2, n).astype(np.int32)
+    valid = RNG.random(n) < 0.9
+    if dtype == np.float32:
+        vals = RNG.normal(size=(n, 2)).astype(dtype)
+        vals[~valid] = np.nan
+    else:
+        info = np.iinfo(dtype)
+        vals = RNG.integers(info.min, info.max, (n, 2),
+                            dtype=np.int64).astype(dtype)
+    args = (jnp.asarray(keys), (jnp.asarray(vals),), num_keys)
+    ker = segment_reduce(*args, op="sum", valid=jnp.asarray(valid),
+                         use_kernel=True, interpret=True)
+    ref = segment_reduce_ref(*args, op="sum", valid=jnp.asarray(valid))
+    if dtype == np.float32:
+        np.testing.assert_allclose(np.asarray(ker.values[0]),
+                                   np.asarray(ref.values[0]),
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(np.asarray(ker.values[0]),
+                                      np.asarray(ref.values[0]))
+    np.testing.assert_array_equal(np.asarray(ker.counts),
+                                  np.asarray(ref.counts))
+    assert int(ker.overflow) == int(ref.overflow)
+
+
 @pytest.mark.parametrize("op", ["max", "min"])
 def test_segment_minmax_ref_vs_numpy(op):
     keys, vals, valid = _case(400, 32, 0, np.float32)
@@ -207,3 +238,62 @@ def test_unknown_strategy_raises():
         segment_reduce(jnp.zeros((4,), jnp.int32),
                        (jnp.zeros((4,), jnp.float32),), 2,
                        strategy="magic")
+
+
+# -- the tuner fails loudly and bounds the tiled kernel's dense work ----------
+
+def test_tuner_candidate_that_raises_fails_the_tune(monkeypatch):
+    from repro.kernels.segment_reduce import ops as seg_ops
+    from repro.kernels.segment_reduce import pick_strategy
+    real = seg_ops.segment_reduce_impl
+
+    def broken(*args, strategy, **kw):
+        if strategy == "sorted":
+            raise RuntimeError("sorted candidate refused")
+        return real(*args, strategy=strategy, **kw)
+
+    monkeypatch.setattr(seg_ops, "segment_reduce_impl", broken)
+    with pytest.raises(RuntimeError, match="sorted candidate refused"):
+        pick_strategy("sum", 333, 7, (jnp.zeros((333,), jnp.int32),))
+
+
+def test_tuner_times_execution_not_the_callers_trace(monkeypatch):
+    import jax
+    from repro.kernels.segment_reduce import tune
+    seen = []
+    real = tune._time_callable
+
+    def spy(fn, *args):
+        seen.append(any(isinstance(a, jax.core.Tracer)
+                        for a in jax.tree.leaves(args)))
+        return real(fn, *args)
+
+    monkeypatch.setattr(tune, "_time_callable", spy)
+
+    @jax.jit
+    def program(keys, vals):
+        return segment_reduce(keys, (vals,), 11, op="sum").values[0]
+
+    program(jnp.arange(341, dtype=jnp.int32) % 11,
+            jnp.ones((341,), jnp.int32))
+    assert seen and not any(seen)
+
+
+@pytest.mark.parametrize("n,num_keys,offered", [
+    (1024 * 155 * 1024, 4 ** 6, True),     # smoke k=6 table
+    (1024 * 149 * 1024, 4 ** 12, False),   # smoke k=12 table
+])
+def test_dense_work_bound_gates_tiled(n, num_keys, offered):
+    from repro.kernels.segment_reduce import tune
+    sig = (((), "int32"),)
+    tiled = [c for c in tune._candidates("tpu", "sum", n, num_keys, sig)
+             if c[0] == "tiled"]
+    assert bool(tiled) is offered
+    assert (tune._default_strategy("tpu", n, num_keys)[0] == "tiled") \
+        is offered
+
+
+def test_every_tiling_fits_scoped_vmem():
+    from repro.kernels.segment_reduce import tune
+    for block, key_block in tune.TILINGS:
+        assert tune._vmem_fits(block, key_block, ncols=4)

@@ -446,3 +446,25 @@ def test_dataset_property_materializes_pending_plan():
     assert traces["n"] == 1
     assert m.plan.empty
     assert ds.num_shards == jax.device_count()
+
+
+def test_aot_compile_error_surfaces_from_the_action(monkeypatch):
+    def refuse(self, *a, **kw):
+        raise RuntimeError("compiler refused the program")
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", refuse)
+    m = MaRe((np.arange(16, dtype=np.int32),), plan_cache=PlanCache()) \
+        .map(op=_counting_op()[0])
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        m.collect()
+
+
+def test_compiled_program_does_not_rejit_on_mismatched_arguments():
+    data = (np.arange(16, dtype=np.int32),)
+    ds = from_host(data, compat.make_mesh((1,), ("data",)))
+    plan = MaRe(ds).map(op=_counting_op()[0]).plan
+    prog = planner_lib.compile_plan(plan, ds, cache=PlanCache())
+    prog.ensure_compiled(ds.records, ds.counts)
+    wider = (jnp.zeros((32,), jnp.int32),)
+    with pytest.raises(Exception):
+        prog(wider, ds.counts)

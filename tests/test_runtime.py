@@ -392,3 +392,28 @@ def test_describe_annotates_cached_lineage_nodes_golden():
     fresh = MaRe(from_host((np.arange(8, dtype=np.int32),), mesh),
                  plan_cache=cache, executor=ex).map(op=op)
     assert "[cached]" not in fresh.describe()
+
+
+# -- persistent compile cache placement ---------------------------------------
+
+def test_compile_cache_honours_env_and_sets_nothing(monkeypatch, tmp_path):
+    from repro import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert updates == []
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    import os
+    from repro import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", path)]

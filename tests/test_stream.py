@@ -3,6 +3,7 @@ windows, live queries — and the exactness contract: the incrementally
 maintained aggregate is bit-identical to a one-shot reduce_by_key over
 the union of all epochs, for ANY partition of the input into epochs."""
 import os
+import tempfile
 import threading
 
 import numpy as np
@@ -25,10 +26,12 @@ def _mesh():
 
 
 def _drop(root, name, lines):
-    path = os.path.join(root, name)
-    with open(path + ".tmp", "w") as f:
+    # stage outside the watched directory (same filesystem), then rename
+    # in: a poller must never list the half-written staging file
+    fd, staged = tempfile.mkstemp(dir=os.path.dirname(root))
+    with os.fdopen(fd, "w") as f:
         f.write("\n".join(lines) + "\n")
-    os.rename(path + ".tmp", path)   # atomic arrival, the object-store way
+    os.rename(staged, os.path.join(root, name))   # atomic arrival
 
 
 def _lines(rng, n):
