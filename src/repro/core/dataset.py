@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs import span
+
 
 @dataclasses.dataclass
 class ShardedDataset:
@@ -144,20 +146,24 @@ def collect_shard(ds: ShardedDataset, shard: int = 0) -> Any:
 
     Slices the shard's block on device and transfers only its valid rows
     to host — a replicated reduce result would otherwise ship every
-    shard's full copy across just to keep one.
+    shard's full copy across just to keep one.  Runs under the
+    ``collect.to_host`` span (args: shard, records, bytes).
     """
     n = ds.num_shards
     if not 0 <= shard < n:
         raise ValueError(f"shard index {shard} out of range for "
                          f"{n}-shard dataset")
-    rows = int(jax.device_get(ds.counts)[shard])
+    with span("collect.to_host", shard=shard) as sp:
+        rows = int(jax.device_get(ds.counts)[shard])
 
-    def one(leaf):
-        cap = leaf.shape[0] // n  # per-leaf shard block
-        lo = shard * cap
-        return jax.device_get(leaf[lo:lo + min(cap, rows)])
+        def one(leaf):
+            cap = leaf.shape[0] // n  # per-leaf shard block
+            lo = shard * cap
+            return jax.device_get(leaf[lo:lo + min(cap, rows)])
 
-    return jax.tree.map(one, ds.records)
+        out = jax.tree.map(one, ds.records)
+        sp.set(records=rows, bytes=_nbytes(out))
+    return out
 
 
 def collect_first_shard(ds: ShardedDataset) -> Any:
@@ -166,15 +172,23 @@ def collect_first_shard(ds: ShardedDataset) -> Any:
 
 
 def collect(ds: ShardedDataset) -> Any:
-    """Gather valid records to host (RDD.collect)."""
-    counts = np.asarray(jax.device_get(ds.counts))
-    cap = ds.capacity
+    """Gather valid records to host (RDD.collect), under the
+    ``collect.to_host`` span (args: records, bytes)."""
+    with span("collect.to_host") as sp:
+        counts = np.asarray(jax.device_get(ds.counts))
+        cap = ds.capacity
 
-    def gather(leaf):
-        host = np.asarray(jax.device_get(leaf))
-        segs: List[np.ndarray] = []
-        for s in range(ds.num_shards):
-            segs.append(host[s * cap:s * cap + counts[s]])
-        return np.concatenate(segs, axis=0) if segs else host[:0]
+        def gather(leaf):
+            host = np.asarray(jax.device_get(leaf))
+            segs: List[np.ndarray] = []
+            for s in range(ds.num_shards):
+                segs.append(host[s * cap:s * cap + counts[s]])
+            return np.concatenate(segs, axis=0) if segs else host[:0]
 
-    return jax.tree.map(gather, ds.records)
+        out = jax.tree.map(gather, ds.records)
+        sp.set(records=int(counts.sum()), bytes=_nbytes(out))
+    return out
+
+
+def _nbytes(tree: Any) -> int:
+    return sum(int(np.asarray(leaf).nbytes) for leaf in jax.tree.leaves(tree))

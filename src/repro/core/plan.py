@@ -15,7 +15,8 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Any, Callable, Hashable, List, Optional, Tuple, Union
+from typing import (Any, Callable, ClassVar, Hashable, List, Optional,
+                    Tuple, Union)
 
 import jax
 import numpy as np
@@ -91,6 +92,9 @@ def op_signature(op: ContainerOp) -> Tuple:
 class MapStage:
     """A fused chain of per-partition ContainerOps (no collectives)."""
 
+    #: Named scope of the stage in a compiled program: ``s<i>.<kind>``.
+    kind: ClassVar[str] = "map"
+
     ops: Tuple[ContainerOp, ...]
 
     def signature(self) -> Tuple:
@@ -103,6 +107,8 @@ class MapStage:
 @dataclasses.dataclass(frozen=True)
 class ShuffleStage:
     """Hash repartition by a vectorized keyBy (one ``all_to_all``)."""
+
+    kind: ClassVar[str] = "shuffle"
 
     key_by: Callable[[Any], jax.Array]
     capacity: Optional[int] = None
@@ -123,6 +129,8 @@ class ShuffleStage:
 @dataclasses.dataclass(frozen=True)
 class ReduceStage:
     """K-level tree aggregation of all partitions down to one."""
+
+    kind: ClassVar[str] = "reduce"
 
     op: ContainerOp
     depth: int = 2
@@ -155,6 +163,8 @@ class KeyedReduceStage:
     per-key partials in a second, combiner-style hop — the skew defense
     when one key dominates the raw record stream.
     """
+
+    kind: ClassVar[str] = "reduce_by_key"
 
     key_by: Callable[[Any], jax.Array]
     op: str

@@ -25,6 +25,8 @@ prefixes via the lineage cache).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import re
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
@@ -33,16 +35,62 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
-from repro.obs import METRICS, TRACER, timed
+from repro.obs import METRICS, op_scopes, timed
 from repro.core.container import Partition, make_partition
 from repro.core.dataset import ShardedDataset
 from repro.core.plan import (KeyedReduceStage, MapStage, Plan, ReduceStage,
-                             ShuffleStage, _apply_chain)
+                             ShuffleStage, _apply_chain, _IdKey)
 from repro.core.shuffle import (keyed_bucket_capacity, salted_dest,
                                 shuffle_partition)
 from repro.core.tree_reduce import (keyed_combine_partition,
                                     keyed_merge_partition,
                                     tree_reduce_partition)
+
+
+#: A MaRe scope in an op's ``op_name`` metadata: the stage's
+#: ``s<i>.<kind>`` (:func:`lower`) and, in a keyed stage, its part
+#: (:func:`_apply_keyed`).
+SCOPE_PATTERN = re.compile(
+    r"(?:^|/)(s\d+\.[a-z_]+(?:/(?:combine|exchange|merge))?)(?=/|$)")
+
+
+def _stable(value: Any) -> str:
+    """Text of a plan signature that every process renders alike:
+    callables by qualified name, identity-keyed params by type."""
+    if isinstance(value, tuple):
+        return "(" + ",".join(_stable(v) for v in value) + ")"
+    if isinstance(value, _IdKey):
+        return type(value.obj).__name__
+    if callable(value):
+        return (f"{getattr(value, '__module__', '')}."
+                f"{getattr(value, '__qualname__', type(value).__name__)}")
+    return repr(value)
+
+
+def program_name(plan: Plan) -> str:
+    """The jit name of ``plan``'s program, e.g.
+    ``mare_kmer_stats_reduce_by_key_3f2a07``: the images and commands of
+    its map and reduce stages and the kinds of the others, in
+    ``[A-Za-z0-9_]`` and at most 48 characters, then six hex digits of a
+    SHA-1 over ``plan.describe()`` and the plan's signature (callables
+    by qualified name).  The same plan gets the same name in every
+    process; plans that differ in any op, command or parameter get
+    different digests."""
+    words = []
+    for st in plan.stages:
+        if isinstance(st, MapStage):
+            ops = st.ops
+        else:
+            words.append(st.kind)
+            ops = (st.op,) if isinstance(st, ReduceStage) else ()
+        for op in ops:
+            words.append(op.image)
+            if op.command not in ("", op.image):
+                words.append(op.command)
+    stem = re.sub(r"[^A-Za-z0-9]+", "_", " ".join(words)).strip("_")
+    text = plan.describe() + "|" + _stable(plan.signature())
+    digest = hashlib.sha1(text.encode()).hexdigest()[:6]
+    return "_".join(w for w in ("mare", stem[:48].rstrip("_"), digest) if w)
 
 
 @dataclasses.dataclass
@@ -52,10 +100,11 @@ class CompiledProgram:
     fn: Callable[..., Tuple]      # (records, counts) -> outputs
     counters: Tuple[Tuple[int, str], ...]  # trailing counter-vector layout
     key: Hashable                 # cache key it was compiled under
-    #: FLOP/byte estimate of the compiled HLO (launch/hlo_cost), filled
-    #: by :meth:`ensure_compiled` when tracing is enabled.
-    cost: Optional[Dict[str, float]] = None
+    #: :func:`program_name` of the plan: the jit name, so the compiled
+    #: module (and a profile's ``XLA Modules`` events) is ``jit_<name>``.
+    name: str
     _aot: Optional[Callable[..., Tuple]] = None   # jax.stages.Compiled
+    _scopes: Optional[Dict[str, str]] = None
 
     def __call__(self, records: Any, counts: jax.Array) -> Tuple:
         if self._aot is not None:
@@ -69,6 +118,19 @@ class CompiledProgram:
         if self._aot is None:
             raise RuntimeError("program not compiled yet")
         return self._aot.as_text()
+
+    def op_scopes(self) -> Dict[str, str]:
+        """``{HLO instruction name: scope path}`` of the compiled program,
+        e.g. ``{"fusion.2": "s0.map", "sort.38":
+        "s1.reduce_by_key/combine"}``: which stage (and which part of a
+        keyed stage) each device op of a profile ran for.  Ops outside
+        every stage scope map to ``unscoped``; a fusion that spans two
+        scopes counts under its root's (:func:`repro.obs.op_scopes`).
+        Parsed from :meth:`as_text` on first call, then kept; never on
+        the dispatch path."""
+        if self._scopes is None:
+            self._scopes = op_scopes(self.as_text(), SCOPE_PATTERN)
+        return self._scopes
 
     @property
     def num_counters(self) -> int:
@@ -89,30 +151,8 @@ class CompiledProgram:
             return
         with timed("plan.lower", phases):
             lowered = self.fn.lower(records, counts)
-        with timed("plan.compile", phases) as sp:
-            compiled = lowered.compile()
-            if TRACER.enabled:
-                # annotate the compile span with what the compiled
-                # program *does* per dispatch, not just how long the
-                # compile took
-                self.cost = _estimate_cost(compiled)
-                if self.cost:
-                    sp.set(**self.cost)
-        self._aot = compiled
-
-
-def _estimate_cost(compiled) -> Optional[Dict[str, float]]:
-    """FLOP/byte estimate of a compiled program via the trip-count-aware
-    HLO walker (launch/hlo_cost) — annotates compile spans so a trace
-    shows not just how long a compile took but how much work the
-    resulting program does per dispatch."""
-    try:
-        from repro.launch.hlo_cost import analyze
-        a = analyze(compiled.as_text())
-        return {"flops": float(a["flops"]), "bytes": float(a["bytes"]),
-                "wire_bytes": float(a["wire_bytes"])}
-    except Exception:
-        return None
+        with timed("plan.compile", phases):
+            self._aot = lowered.compile()
 
 
 class PlanCache:
@@ -213,69 +253,82 @@ def _apply_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
     can still overflow, which raises at action time with the
     ``max_send_count`` diagnostic as the tight retry capacity.
 
+    Named scopes (``op_scopes``): ``combine`` (key and value selection,
+    the map-side combiner or the compaction), ``exchange`` (bucketing,
+    the all-to-all and its counters; both hops when salted) and
+    ``merge`` (the post-exchange segment reduce; both merges).
+
     Counters (order = ``stage_counter_kinds``): key_overflow,
     shuffle_dropped, exchanged_records, max_send_count (max per-dest send
     this shard; max-reduced across shards by the executor),
     exchange_buffer_rows (static per-shard buffer allocation).
     """
-    keys = jnp.asarray(stage.key_by(part.records)).astype(jnp.int32)
-    values = (stage.value_by(part.records) if stage.value_by is not None
-              else part.records)
-    valid = part.mask()
     num_keys = stage.num_keys
     salt = 1 if stage.combiner else max(1, int(stage.salt))
-    if stage.combiner:
-        send, overflow = keyed_combine_partition(
-            keys, values, valid, num_keys, op=stage.op,
-            use_kernel=stage.use_kernel)
-        default_cap = keyed_bucket_capacity(num_keys, axis_size)
-    else:
-        in_range = (keys >= 0) & (keys < num_keys)
-        ok = valid & in_range
-        overflow = jnp.sum(valid & ~in_range).astype(jnp.int32)
-        # compact surviving records to the front (count semantics)
-        order = jnp.argsort(~ok, stable=True)
-        recs = (jnp.take(keys, order, mode="clip"),
-                jax.tree.map(lambda l: jnp.take(l, order, axis=0,
-                                                mode="clip"), values),
-                jnp.take(ok.astype(jnp.int32), order, mode="clip"))
-        send = make_partition(recs, jnp.sum(ok).astype(jnp.int32))
-        if salt > 1:
-            # perfectly-spread hot key needs cap_in/spread; 2x headroom
-            # for overlapping salt windows of distinct keys. A key can
-            # never spread over more destinations than exist, so the
-            # spread factor is capped at axis_size (salt > axis_size on
-            # a small mesh must not shrink the buffer below what one
-            # destination can receive).
-            spread = min(salt, axis_size)
-            default_cap = min(part.capacity,
-                              2 * ((part.capacity + spread - 1) // spread))
+    with jax.named_scope("combine"):
+        keys = jnp.asarray(stage.key_by(part.records)).astype(jnp.int32)
+        values = (stage.value_by(part.records) if stage.value_by is not None
+                  else part.records)
+        valid = part.mask()
+        if stage.combiner:
+            send, overflow = keyed_combine_partition(
+                keys, values, valid, num_keys, op=stage.op,
+                use_kernel=stage.use_kernel)
+            default_cap = keyed_bucket_capacity(num_keys, axis_size)
         else:
-            default_cap = part.capacity  # any shard may ship every record
+            in_range = (keys >= 0) & (keys < num_keys)
+            ok = valid & in_range
+            overflow = jnp.sum(valid & ~in_range).astype(jnp.int32)
+            # compact surviving records to the front (count semantics)
+            order = jnp.argsort(~ok, stable=True)
+            recs = (jnp.take(keys, order, mode="clip"),
+                    jax.tree.map(lambda l: jnp.take(l, order, axis=0,
+                                                    mode="clip"), values),
+                    jnp.take(ok.astype(jnp.int32), order, mode="clip"))
+            send = make_partition(recs, jnp.sum(ok).astype(jnp.int32))
+            if salt > 1:
+                # perfectly-spread hot key needs cap_in/spread; 2x headroom
+                # for overlapping salt windows of distinct keys. A key can
+                # never spread over more destinations than exist, so the
+                # spread factor is capped at axis_size (salt > axis_size on
+                # a small mesh must not shrink the buffer below what one
+                # destination can receive).
+                spread = min(salt, axis_size)
+                default_cap = min(part.capacity,
+                                  2 * ((part.capacity + spread - 1) // spread))
+            else:
+                default_cap = part.capacity  # any shard may ship every record
     cap = stage.capacity or default_cap
-    dest = (salted_dest(send.records[0], axis_size, salt)
-            if salt > 1 else None)
-    res = shuffle_partition(send, send.records[0], axis_name=axis,
-                            axis_size=axis_size, capacity=cap, dest=dest)
-    exchanged = jnp.sum(res.send_counts).astype(jnp.int32)
-    max_send = jnp.max(res.send_counts).astype(jnp.int32)
+    with jax.named_scope("exchange"):
+        dest = (salted_dest(send.records[0], axis_size, salt)
+                if salt > 1 else None)
+        res = shuffle_partition(send, send.records[0], axis_name=axis,
+                                axis_size=axis_size, capacity=cap,
+                                dest=dest)
+        exchanged = jnp.sum(res.send_counts).astype(jnp.int32)
+        max_send = jnp.max(res.send_counts).astype(jnp.int32)
     buffer_rows = axis_size * cap
-    out, merge_overflow = keyed_merge_partition(
-        res.part, num_keys, op=stage.op, use_kernel=stage.use_kernel)
+    with jax.named_scope("merge"):
+        out, merge_overflow = keyed_merge_partition(
+            res.part, num_keys, op=stage.op, use_kernel=stage.use_kernel)
     dropped = res.dropped
     if salt > 1:
         # hop 2: per-key partials back to their hash owner (combiner-style,
         # exact-lossless capacity) + final merge
         cap2 = keyed_bucket_capacity(num_keys, axis_size)
-        res2 = shuffle_partition(out, out.records[0], axis_name=axis,
-                                 axis_size=axis_size, capacity=cap2)
-        exchanged = exchanged + jnp.sum(res2.send_counts).astype(jnp.int32)
-        max_send = jnp.maximum(max_send,
-                               jnp.max(res2.send_counts).astype(jnp.int32))
+        with jax.named_scope("exchange"):
+            res2 = shuffle_partition(out, out.records[0], axis_name=axis,
+                                     axis_size=axis_size, capacity=cap2)
+            exchanged = exchanged + jnp.sum(res2.send_counts).astype(
+                jnp.int32)
+            max_send = jnp.maximum(
+                max_send, jnp.max(res2.send_counts).astype(jnp.int32))
         buffer_rows += axis_size * cap2
         dropped = dropped + res2.dropped
-        out, merge2_overflow = keyed_merge_partition(
-            res2.part, num_keys, op=stage.op, use_kernel=stage.use_kernel)
+        with jax.named_scope("merge"):
+            out, merge2_overflow = keyed_merge_partition(
+                res2.part, num_keys, op=stage.op,
+                use_kernel=stage.use_kernel)
         merge_overflow = merge_overflow + merge2_overflow
     return out, [(overflow + merge_overflow).astype(jnp.int32),
                  dropped.astype(jnp.int32), exchanged, max_send,
@@ -334,13 +387,17 @@ def lower(plan: Plan, axis: str, axis_size: int):
     where ``counters`` is an int32 vector laid out per
     ``plan.counter_specs()`` (omitted when the plan has none): shuffle
     drop counts, keyed-reduce key-table overflow, exchanged-record volume.
+    Stage ``i`` runs under ``jax.named_scope("s<i>.<kind>")`` (e.g.
+    ``s0.map``, ``s1.reduce_by_key``): metadata only, the compiled ops
+    are the same.
     """
 
     def interior(records, counts):
         part = make_partition(records, counts[0])
         counters: List[jax.Array] = []
         for i, stage in enumerate(plan.stages):
-            part, cs = _apply_stage(stage, part, axis, axis_size, i)
+            with jax.named_scope(f"s{i}.{stage.kind}"):
+                part, cs = _apply_stage(stage, part, axis, axis_size, i)
             counters.extend(cs)
         outs = (part.records, part.count[None])
         if counters:
@@ -367,21 +424,25 @@ def compile_plan(plan: Plan, ds: ShardedDataset,
                  cache: Optional[PlanCache] = None,
                  phases: Optional[Dict[str, float]] = None
                  ) -> CompiledProgram:
-    """Memoized lowering of ``plan`` against ``ds``'s shapes and mesh.
-    ``phases`` (when given) accumulates build time under ``plan.build``."""
+    """Memoized lowering of ``plan`` against ``ds``'s shapes and mesh,
+    jitted under :func:`program_name`.  ``phases`` (when given)
+    accumulates build time under ``plan.build``."""
     cache = cache if cache is not None else DEFAULT_CACHE
     mesh, axis = ds.mesh, ds.axis
     key = program_key(plan, ds)
 
     def build() -> CompiledProgram:
         counters = plan.counter_specs()
+        name = program_name(plan)
         interior = lower(plan, axis, int(mesh.shape[axis]))
+        interior.__name__ = interior.__qualname__ = name
         out_specs = (P(axis), P(axis)) + ((P(axis),) if counters else ())
         check_vma = False if _plan_uses_pallas(plan) else None
         fn = jax.jit(compat.shard_map(
             interior, mesh=mesh, in_specs=(P(axis), P(axis)),
             out_specs=out_specs, check_vma=check_vma))
-        return CompiledProgram(fn=fn, counters=counters, key=key)
+        return CompiledProgram(fn=fn, counters=counters, key=key,
+                               name=name)
 
     return cache.get_or_compile(key, build, phases=phases)
 

@@ -18,6 +18,13 @@ JSON-serializable ``args``.  Nesting is by containment on a thread —
 Perfetto and ``chrome://tracing`` both render stacked slices without
 explicit parent links.  Export with :meth:`Tracer.export` (or
 ``MaRe.trace_to``) and load the file straight into https://ui.perfetto.dev.
+
+While enabled, every span also opens a ``jax.profiler.TraceAnnotation``
+of the same name, so a ``jax.profiler`` trace taken meanwhile holds the
+spans on its ``/host:CPU`` plane, on the thread that ran them and on the
+device events' clock: device idle time can be put down to what the
+program was doing.  (JAX is imported by :meth:`Tracer.start`, never by
+the disabled path.)
 """
 from __future__ import annotations
 
@@ -49,9 +56,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records [enter, exit) and appends to the ring."""
+    """One live span: records [enter, exit) and appends to the ring, and
+    holds the profiler annotation of the same name open meanwhile."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]) -> None:
@@ -60,11 +68,14 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        self._note = self._tracer._annotation(self.name)
+        self._note.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.monotonic()
+        self._note.__exit__(None, None, None)
         self._tracer._record(self.name, self._t0, t1, self.args)
 
     def set(self, **args: Any) -> None:
@@ -90,6 +101,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._enabled = False
         self._epoch = time.monotonic()
+        self._annotation: Any = None     # jax.profiler.TraceAnnotation
         self.events_total = 0
 
     # -- control -------------------------------------------------------------
@@ -99,8 +111,11 @@ class Tracer:
         return self._enabled
 
     def start(self, clear: bool = True) -> "Tracer":
-        """Attach the ring sink: spans/instants record from now on."""
+        """Attach the ring sink: spans/instants record from now on, and
+        each span opens a profiler annotation of its name."""
+        from jax.profiler import TraceAnnotation
         with self._lock:
+            self._annotation = TraceAnnotation
             if clear:
                 self._events.clear()
                 self.events_total = 0

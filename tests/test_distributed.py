@@ -12,7 +12,7 @@ SRC = os.path.join(HERE, "..", "src")
 
 SCRIPTS = ["mare_e2e.py", "moe_sharded.py", "grad_sync.py",
            "elastic_reshard.py", "dryrun_small.py", "ssm_cp.py",
-           "ingest_waves.py", "keyed_skew.py"]
+           "ingest_waves.py", "keyed_skew.py", "stage_scopes.py"]
 
 
 @pytest.mark.parametrize("script", SCRIPTS)

@@ -206,3 +206,106 @@ def test_disabled_tracing_overhead_under_5pct_of_small_action():
             pass
     per_span = (time.perf_counter() - t0) / n
     assert per_span * 16 < 0.05 * action_s, (per_span, action_s)
+
+
+# -- spans on the profiler's clock --------------------------------------------
+
+def _host_span_names(log_dir) -> set:
+    from jax.profiler import ProfileData
+    (path,) = list(log_dir.rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    return {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def _kmer_job(path, ex):
+    from repro.io import fasta_source
+    m = MaRe.from_source(fasta_source(str(path)), executor=ex)
+    m.plan_cache = PlanCache()
+    return (m.map(image="kmer-stats", k=4)
+            .reduce_by_key(lambda r: r[0], value_by=lambda r: (r[1],),
+                           op="sum").collect())
+
+
+def test_spans_reach_the_profiler_host_plane_only_while_tracing(tmp_path):
+    import jax
+    rng = np.random.default_rng(3)
+    fa = tmp_path / "reads.fa"
+    fa.write_text("".join(
+        f">r{i}\n{''.join(rng.choice(list('ACGT'), 40))}\n"
+        for i in range(64)))
+    program_spans = {"ingest", "dispatch", "device_wait", "collect.to_host"}
+    on, off = tmp_path / "on", tmp_path / "off"
+    with jax.profiler.trace(str(on)):
+        with tracing() as t:
+            _kmer_job(fa, _executor())
+    assert program_spans <= {e["name"] for e in t.events()}
+    assert program_spans <= _host_span_names(on)
+    to_host = next(e for e in t.events() if e["name"] == "collect.to_host")
+    assert to_host["args"]["records"] == 256       # every 4-mer occurs
+    assert to_host["args"]["bytes"] == 256 * 3 * 4
+    with jax.profiler.trace(str(off)):
+        _kmer_job(fa, _executor())
+    assert not program_spans & _host_span_names(off)
+
+
+def test_disabled_span_constructs_no_annotation(monkeypatch):
+    def refuse(name):
+        raise AssertionError(f"annotation {name!r} built while off")
+
+    monkeypatch.setattr(TRACER, "_annotation", refuse)
+    assert not TRACER.enabled
+    with span("x") as s, timed("y", {}):
+        s.set(k=1)
+    assert span("x") is span("y")           # the shared null span
+
+
+# -- op scopes from a compiled program's text ---------------------------------
+
+_HLO = '''HloModule jit_mare_x_0a1b2c, entry_computation_layout={()}
+
+%fused_computation (p0: s32[8]) -> s32[8] {
+  %p0 = s32[8]{0} parameter(0)
+  %add.1 = s32[8]{0} add(%p0, %p0), metadata={op_name="jit(f)/s0.map/add"}
+  ROOT %copy.3 = s32[8]{0} copy(%add.1)
+}
+
+%fused_computation.1 (p1: s32[8]) -> s32[8] {
+  %p1 = s32[8]{0} parameter(0)
+  %neg.1 = s32[8]{0} negate(%p1), metadata={op_name="jit(f)/s1.reduce_by_key/merge/neg"}
+  %neg.2 = s32[8]{0} negate(%neg.1), metadata={op_name="jit(f)/s1.reduce_by_key/merge/neg"}
+  ROOT %mul.1 = s32[8]{0} multiply(%neg.2, %p1)
+}
+
+ENTRY %main (param.1: s32[8]) -> s32[8] {
+  %param.1 = s32[8]{0} parameter(0), metadata={op_name="records"}
+  %copy.1 = s32[8]{0} copy(%param.1)
+  %fusion = s32[8]{0} fusion(%copy.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/s0.map/add"}
+  %sort.2 = s32[8]{0} sort(%fusion), dimensions={0}, metadata={op_name="jit(f)/s1.reduce_by_key/combine/jit(sort)/sort"}
+  %all-to-all.1 = s32[8]{0} all-to-all(%sort.2), metadata={op_name="jit(f)/s1.reduce_by_key/exchange/all_to_all"}
+  %fusion.1 = s32[8]{0} fusion(%all-to-all.1), kind=kLoop, calls=%fused_computation.1
+  %copy.2 = s32[8]{0} copy(%fusion.1)
+  ROOT %tuple = (s32[8]{0}) tuple(%copy.2), metadata={op_name="jit(f)/s10.reduce/x"}
+}
+'''
+
+
+def test_op_scopes_reads_own_callee_operand_and_user_scopes():
+    import re
+    from repro.obs import UNSCOPED, op_scopes
+    scope = re.compile(
+        r"(?:^|/)(s\d+\.[a-z_]+(?:/(?:combine|exchange|merge))?)(?=/|$)")
+    got = op_scopes(_HLO, scope)
+    assert got["fusion"] == got["add.1"] == "s0.map"       # own metadata
+    assert got["sort.2"] == "s1.reduce_by_key/combine"
+    assert got["all-to-all.1"] == "s1.reduce_by_key/exchange"
+    assert got["tuple"] == "s10.reduce"
+    # a fusion with no metadata: its root has none either, so the scope
+    # most of its computation's instructions hold
+    assert got["fusion.1"] == "s1.reduce_by_key/merge"
+    assert got["copy.2"] == "s1.reduce_by_key/merge"       # its operand's
+    assert got["copy.1"] == "s0.map"                       # its user's
+    assert got["copy.3"] == "s0.map"                       # operand add.1
+    assert got["p0"] == "s0.map"                           # user add.1
+    assert got["param.1"] == UNSCOPED        # its only user has no scope

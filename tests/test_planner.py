@@ -2,6 +2,8 @@
 shuffle-overflow accounting, keyed aggregation (single device; multi-device
 coverage lives in tests/distributed/mare_e2e.py)."""
 import dataclasses
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -468,3 +470,82 @@ def test_compiled_program_does_not_rejit_on_mismatched_arguments():
     wider = (jnp.zeros((32,), jnp.int32),)
     with pytest.raises(Exception):
         prog(wider, ds.counts)
+
+
+# -- program names and stage scopes -------------------------------------------
+
+def _gc_plan(chars):
+    records = {"data": np.zeros((8, 4), np.uint8),
+               "len": np.full((8,), 4, np.int32)}
+    return (MaRe(records, plan_cache=PlanCache())
+            .map(image="ubuntu", command=f"grep-chars {chars}")
+            .reduce(image="ubuntu", command="awk-sum")).plan
+
+
+def test_program_names_differ_by_grep_chars_argument_and_are_stable():
+    import subprocess
+    import sys
+    queries = ("GC", "AT", "N", "ACGT")
+    names = [planner_lib.program_name(_gc_plan(q)) for q in queries]
+    assert len(set(names)) == len(queries)
+    assert all(re.fullmatch(r"mare_ubuntu_grep_chars_[A-Z]+_reduce_ubuntu_"
+                            r"awk_sum_[0-9a-f]{6}", n) for n in names)
+    assert names == [planner_lib.program_name(_gc_plan(q))
+                     for q in queries]
+    # the same in a fresh process (a digest of hashlib, not hash())
+    code = ("import sys; sys.path.insert(0, 'tests');"
+            "import test_planner as t;"
+            "print(' '.join(t.planner_lib.program_name(t._gc_plan(q))"
+            f" for q in {queries!r}))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env={**os.environ, "PYTHONHASHSEED": "7"})
+    assert out.stdout.split() == names
+
+
+def _mod16(part, **kw):
+    return make_partition(((part.records[0] * 7) % 16,), part.count)
+
+
+def test_compiled_program_is_named_after_its_plan():
+    m = (MaRe((np.arange(64, dtype=np.int32),), plan_cache=PlanCache())
+         .map(op=ContainerOp(image="test/mod16", fn=_mod16))
+         .reduce_by_key(_key_first, op="sum", num_keys=16))
+    plan = m.plan
+    m.collect()
+    (prog,) = m.plan_cache.programs()
+    assert prog.name == planner_lib.program_name(plan)
+    assert prog.as_text().startswith(f"HloModule jit_{prog.name}")
+    scopes = set(prog.op_scopes().values())
+    assert {"s0.map", "s1.reduce_by_key/combine", "s1.reduce_by_key/merge",
+            "s1.reduce_by_key/exchange"} <= scopes
+
+
+def _strip_metadata(hlo: str) -> str:
+    text = re.sub(r",? metadata=\{[^}]*\}", "", hlo)
+    text = re.sub(r"\n\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n.*?(?=\n\n)", "", text, flags=re.S)
+    return text.split("\n", 1)[1]               # drop the module's name
+
+
+def test_stage_scopes_change_metadata_only(monkeypatch):
+    import contextlib
+    data = (np.arange(64, dtype=np.int32),)
+    ds = from_host(data, compat.make_mesh((1,), ("data",)))
+    plan = (MaRe(ds).map(op=ContainerOp(image="test/mod16", fn=_mod16))
+            .reduce_by_key(_key_first, op="sum", num_keys=16,
+                           combiner=False, salt=2).plan)
+
+    def compiled_text() -> str:
+        prog = planner_lib.compile_plan(plan, ds, cache=PlanCache())
+        prog.ensure_compiled(ds.records, ds.counts)
+        return prog.as_text()
+
+    scoped = compiled_text()
+    assert 'op_name="jit(mare_' in scoped and "/s1.reduce_by_key/merge/" \
+        in scoped
+    monkeypatch.setattr(planner_lib.jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled_text()
+    assert "s1.reduce_by_key" not in bare
+    assert _strip_metadata(scoped) == _strip_metadata(bare)
