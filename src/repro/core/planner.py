@@ -103,6 +103,10 @@ class CompiledProgram:
     #: :func:`program_name` of the plan: the jit name, so the compiled
     #: module (and a profile's ``XLA Modules`` events) is ``jit_<name>``.
     name: str
+    #: :func:`local_keyed_stages` of the plan on its mesh: which keyed
+    #: stages were lowered for one device (``report().diagnostics``
+    #: ``stage<i>.local_keyed``).
+    local_keyed: Dict[int, int] = dataclasses.field(default_factory=dict)
     _aot: Optional[Callable[..., Tuple]] = None   # jax.stages.Compiled
     _scopes: Optional[Dict[str, str]] = None
 
@@ -149,7 +153,10 @@ class CompiledProgram:
         """
         if self._aot is not None:
             return
-        with timed("plan.lower", phases):
+        args = ({"local_keyed": {f"s{i}": v for i, v in
+                                 self.local_keyed.items()}}
+                if self.local_keyed else {})
+        with timed("plan.lower", phases, **args):
             lowered = self.fn.lower(records, counts)
         with timed("plan.compile", phases):
             self._aot = lowered.compile()
@@ -253,10 +260,28 @@ def _apply_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
     can still overflow, which raises at action time with the
     ``max_send_count`` diagnostic as the tight retry capacity.
 
+    One device (``axis_size == 1``, :func:`local_keyed_stages`): the
+    exchange would only deliver to the shard itself the first ``cap``
+    records it sends, in their order, so it is not lowered; no hash, no
+    bucketing sort, no buffers.  The combiner's table is then already
+    the answer (one record per key, ascending, capacity ``num_keys``),
+    so no merge runs either: a merge of distinct keys would only fold
+    each record into the identity and compact the table again.  With
+    ``combiner=False`` one merge remains, the only fold; the salted
+    second hop and its merge would refold a folded table, so they go
+    too.  The counters still read what the exchange would have:
+    ``shuffle_dropped`` is ``max(sent - cap, 0)`` (a too-small
+    ``capacity=`` still raises), ``exchanged_records`` and
+    ``max_send_count`` what crosses to the shard itself (hop 2's table
+    included when salted), ``exchange_buffer_rows`` the buffer rows the
+    exchange would allocate.
+
     Named scopes (``op_scopes``): ``combine`` (key and value selection,
     the map-side combiner or the compaction), ``exchange`` (bucketing,
     the all-to-all and its counters; both hops when salted) and
-    ``merge`` (the post-exchange segment reduce; both merges).
+    ``merge`` (the post-exchange segment reduce; both merges).  On one
+    device there is no ``exchange`` scope, and no ``merge`` scope with
+    the combiner on.
 
     Counters (order = ``stage_counter_kinds``): key_overflow,
     shuffle_dropped, exchanged_records, max_send_count (max per-dest send
@@ -299,6 +324,8 @@ def _apply_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
             else:
                 default_cap = part.capacity  # any shard may ship every record
     cap = stage.capacity or default_cap
+    if axis_size == 1:
+        return _apply_keyed_local(stage, send, overflow, cap, salt)
     with jax.named_scope("exchange"):
         dest = (salted_dest(send.records[0], axis_size, salt)
                 if salt > 1 else None)
@@ -333,6 +360,40 @@ def _apply_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
     return out, [(overflow + merge_overflow).astype(jnp.int32),
                  dropped.astype(jnp.int32), exchanged, max_send,
                  jnp.full((), buffer_rows, jnp.int32)]
+
+
+def _apply_keyed_local(stage: KeyedReduceStage, send: Partition,
+                       overflow: jax.Array, cap: int, salt: int
+                       ) -> Tuple[Partition, List[jax.Array]]:
+    """:func:`_apply_keyed` after its ``combine`` scope, on one device:
+    the shard "receives" the first ``cap`` records of ``send``, and
+    only a stage without the combiner merges them."""
+    num_keys = stage.num_keys
+    sent = jnp.minimum(send.count, cap).astype(jnp.int32)
+    dropped = send.count - sent
+    exchanged = max_send = sent
+    buffer_rows = cap
+    out = make_partition(send.records, sent)
+    if not stage.combiner:
+        with jax.named_scope("merge"):
+            out, merge_overflow = keyed_merge_partition(
+                out, num_keys, op=stage.op, use_kernel=stage.use_kernel)
+        overflow = overflow + merge_overflow
+        if salt > 1:
+            # hop 2 would send the merged table to its one owner: no
+            # more records than hop 1 sent, so max_send stands
+            exchanged = exchanged + out.count
+            buffer_rows += keyed_bucket_capacity(num_keys, 1)
+    return out, [overflow.astype(jnp.int32), dropped.astype(jnp.int32),
+                 exchanged, max_send, jnp.full((), buffer_rows, jnp.int32)]
+
+
+def local_keyed_stages(plan: Plan, axis_size: int) -> Dict[int, int]:
+    """``{i: 1 or 0}`` for each keyed stage ``i`` of ``plan``: 1 where
+    :func:`_apply_keyed` lowers it without the exchange, which is where
+    the mesh axis has one device."""
+    return {i: int(axis_size == 1) for i, st in enumerate(plan.stages)
+            if isinstance(st, KeyedReduceStage)}
 
 
 def _validate_mount(mount, records, stage_idx: int, op_name: str,
@@ -434,7 +495,8 @@ def compile_plan(plan: Plan, ds: ShardedDataset,
     def build() -> CompiledProgram:
         counters = plan.counter_specs()
         name = program_name(plan)
-        interior = lower(plan, axis, int(mesh.shape[axis]))
+        axis_size = int(mesh.shape[axis])
+        interior = lower(plan, axis, axis_size)
         interior.__name__ = interior.__qualname__ = name
         out_specs = (P(axis), P(axis)) + ((P(axis),) if counters else ())
         check_vma = False if _plan_uses_pallas(plan) else None
@@ -442,7 +504,9 @@ def compile_plan(plan: Plan, ds: ShardedDataset,
             interior, mesh=mesh, in_specs=(P(axis), P(axis)),
             out_specs=out_specs, check_vma=check_vma))
         return CompiledProgram(fn=fn, counters=counters, key=key,
-                               name=name)
+                               name=name,
+                               local_keyed=local_keyed_stages(plan,
+                                                              axis_size))
 
     return cache.get_or_compile(key, build, phases=phases)
 

@@ -45,6 +45,8 @@ def keyed_bucket_capacities(num_keys: int, axis_size: int) -> np.ndarray:
     Runs chunked so a 4**15-sized key space costs MiBs of host scratch,
     not GiBs.  (Host-side mirror of :func:`hash_keys`; keep in lockstep.)
     """
+    if axis_size == 1:                # every key's owner is shard 0
+        return np.array([num_keys], np.int64)
     mask = np.uint64(0xFFFFFFFF)
     buckets = np.zeros((axis_size,), np.int64)
     chunk = 1 << 22

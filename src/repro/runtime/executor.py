@@ -118,7 +118,10 @@ def execute(ds: ShardedDataset, plan: Plan, *,
     stage-at-a-time execution (each stage its own program, counters
     synced after each stage) — the pre-planner schedule, kept for
     debugging and benchmarking.  ``diagnostics``, when given, is filled
-    with per-counter totals keyed ``"stage<i>.<kind>"``; ``phases``,
+    with per-counter totals keyed ``"stage<i>.<kind>"``, and for each
+    keyed stage ``"stage<i>.local_keyed"``: 1 where the program lowered
+    it for one device, without the exchange (a host-side fact of the
+    compiled program, not a device counter); ``phases``,
     when given, accumulates the per-phase wall breakdown (lower /
     compile / dispatch / device_wait / counter_sync) that
     :class:`~repro.runtime.reports.ActionReport.phases` surfaces.
@@ -132,6 +135,9 @@ def execute(ds: ShardedDataset, plan: Plan, *,
                          stage_offset=stage_offset + i, phases=phases)
         return ds
     prog = planner_lib.compile_plan(plan, ds, cache, phases=phases)
+    if diagnostics is not None:
+        for i, local in prog.local_keyed.items():
+            diagnostics[f"stage{i + stage_offset}.local_keyed"] = local
     # AOT split: lowering + XLA compile become their own phases/spans
     # (zero on a plan-cache hit) instead of hiding in the first dispatch
     prog.ensure_compiled(ds.records, ds.counts, phases)

@@ -1,7 +1,8 @@
 """2-device stage scopes: the program of ``from_source -> map(kmer-stats)
 -> reduce_by_key`` names its ops by stage, and its keyed stage's
 combine, exchange (an all-to-all between the two devices) and merge,
-with and without the salted second hop."""
+with and without the salted second hop; on two devices the stage is not
+lowered for one."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import tempfile
@@ -29,6 +30,7 @@ for kw in ({}, {"combiner": False, "salt": 2}):
         lambda r: r[0], value_by=lambda r: (r[1],), op="sum", **kw)
     keys, (sums,), counts = q.collect()
     assert len(keys) == 256 and int(counts.sum()) == 256 * 57, kw
+    assert q.report().diagnostics["stage1.local_keyed"] == 0, kw
     (prog,) = cache.programs()
     scopes = prog.op_scopes()
     assert PARTS <= set(scopes.values()), (kw, set(scopes.values()))
