@@ -13,7 +13,7 @@ message, and the planner can type-check whole pipelines before tracing.
 from __future__ import annotations
 
 import inspect
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -110,34 +110,97 @@ posix = container_op("posix", manifest=POSIX_MANIFEST)(_posix_entry)
 
 _BASE_CODES = {65: 0, 67: 1, 71: 2, 84: 3}   # A C G T -> 2-bit codes
 
+#: Largest k whose packed code is one int32 (``4**k`` keys, a dense
+#: table); up to :data:`KMER_MAX_K` a code takes two uint32 words.
+KMER_ONE_WORD_K = 15
+KMER_MAX_K = 31
+
+
+def _kmer_key_field(k: int):
+    return (field(jnp.int32) if k <= KMER_ONE_WORD_K
+            else field(jnp.uint32, (2,)))
+
+
+def _canonical_token(token: str) -> bool:
+    """The ``canonical`` flag of the ``kmer-stats`` grammar."""
+    if token != "canonical":
+        raise ValueError(f"expected 'canonical', got {token!r}")
+    return True
+
+
 KMER_MANIFEST = ImageManifest(
     input_schema=bytes_record_schema(),
-    output_schema=Schema((field(jnp.int32), field(jnp.int32))),
+    output_schema=lambda schema, env: Schema(
+        (_kmer_key_field(env["k"]), field(jnp.int32))),
     # every record yields at most W - k + 1 windows
     out_capacity=lambda cap, env: cap * (env["W"] - env["k"] + 1),
-    # packed 2-bit keys cover [0, 4**k) — downstream key tables can be
-    # sized (and bounds-checked) at plan time, FastKmer-style
-    key_space=lambda env: 4 ** env["k"],
+    # one-word packed keys cover [0, 4**k) — downstream key tables can be
+    # sized (and bounds-checked) at plan time, FastKmer-style; a
+    # two-word key has no table (reduce_by_key sorts it)
+    key_space=lambda env: (4 ** env["k"] if env["k"] <= KMER_ONE_WORD_K
+                           else None),
     commands=(CommandSpec(
-        "kmer-stats", args=(ArgSpec("k", type=int, required=False),)),),
+        "kmer-stats", args=(ArgSpec("k", type=int, required=False),
+                            ArgSpec("canonical", type=_canonical_token,
+                                    required=False))),),
     default_command="kmer-stats")
 
 
+def _kmer_windows(code: jax.Array, k: int, canonical: bool
+                  ) -> Tuple[jax.Array, ...]:
+    """Packed codes of every k-base window of the 2-bit ``code`` ``[cap,
+    W]``: ``(int32,)`` for ``k <= 15``, ``(high, low)`` uint32 words
+    beyond.  ``canonical`` takes the lesser of each window's code and its
+    reverse complement's (base ``j`` of the window, complemented, is
+    digit ``j`` of the reverse complement, least significant first)."""
+    nw = code.shape[1] - k + 1
+    if k <= KMER_ONE_WORD_K:
+        fwd = jnp.zeros((code.shape[0], nw), jnp.int32)
+        rc = jnp.zeros_like(fwd)
+        for j in range(k):
+            c = code[:, j:j + nw]
+            fwd = fwd * 4 + c
+            rc = rc | ((3 - c) << (2 * j))
+        return (jnp.minimum(fwd, rc) if canonical else fwd,)
+    code = code.astype(jnp.uint32)
+    zeros = jnp.zeros((code.shape[0], nw), jnp.uint32)
+    hi, lo, rc_hi, rc_lo = zeros, zeros, zeros, zeros
+    for j in range(k):
+        c = code[:, j:j + nw]
+        hi = (hi << 2) | (lo >> 30)
+        lo = (lo << 2) | c
+        if canonical:
+            if 2 * j < 32:
+                rc_lo = rc_lo | ((3 - c) << (2 * j))
+            else:
+                rc_hi = rc_hi | ((3 - c) << (2 * j - 32))
+    if canonical:
+        take = (rc_hi < hi) | ((rc_hi == hi) & (rc_lo < lo))
+        hi, lo = jnp.where(take, rc_hi, hi), jnp.where(take, rc_lo, lo)
+    return hi, lo
+
+
 @container_op("kmer-stats", manifest=KMER_MANIFEST, k=8)
-def kmer_stats(part: Partition, k: int = 8, **kw: Any) -> Partition:
+def kmer_stats(part: Partition, k: int = 8, canonical: bool = False,
+               **kw: Any) -> Partition:
     """Emit one ``(packed k-mer key, 1)`` record per k-mer occurrence.
 
     Input: byte records ``{"data": uint8 [cap, W], "len": int32 [cap]}``
     (the repro.io FASTA contract — each record is one sequence line, so
-    k-mers never span records).  Output records: ``(codes int32, ones
-    int32)`` with the 2-bit packing ``A=0 C=1 G=2 T=3`` (case-insensitive);
-    windows containing any other base (N, gaps) are skipped.  ``k`` comes
-    from the param or the command grammar (``kmer-stats 8``); ``k <= 15``
-    keeps codes within int32, and ``num_keys = 4**k`` downstream (declared
-    as the manifest's ``key_space``, so ``reduce_by_key`` can infer it).
+    k-mers never span records).  Output records: ``(codes, ones int32)``
+    with the 2-bit packing ``A=0 C=1 G=2 T=3`` (case-insensitive), first
+    base most significant; windows containing any other base (N, gaps)
+    are skipped.  ``k`` (1..31) and ``canonical`` come from the params or
+    the command grammar (``kmer-stats 21 canonical``).  For ``k <= 15``
+    a code is one int32 and ``num_keys = 4**k`` downstream (declared as
+    the manifest's ``key_space``, so ``reduce_by_key`` can infer it);
+    for ``16 <= k <= 31`` it is one uint32 ``[2]`` leaf, high word then
+    low (``2k - 32`` and 32 bits), which ``reduce_by_key`` folds by
+    sorting.  ``canonical`` counts a k-mer and its reverse complement as
+    one: the lesser of the two codes.
     """
-    if not 1 <= k <= 15:
-        raise ValueError(f"kmer-stats needs 1 <= k <= 15, got {k}")
+    if not 1 <= k <= KMER_MAX_K:
+        raise ValueError(f"kmer-stats needs 1 <= k <= {KMER_MAX_K}, got {k}")
     data = part.records["data"]
     lens = part.records["len"]
     cap, width = data.shape
@@ -151,19 +214,61 @@ def kmer_stats(part: Partition, k: int = 8, **kw: Any) -> Partition:
         hit = upper == byte
         code = jnp.where(hit, c, code)
         base_ok = base_ok | hit
-    acc = jnp.zeros((cap, nw), jnp.int32)
-    window_ok = jnp.ones((cap, nw), bool)
-    for j in range(k):
-        acc = acc * 4 + code[:, j:j + nw]
-        window_ok = window_ok & base_ok[:, j:j + nw]
+    if k > KMER_ONE_WORD_K or canonical:
+        words = _kmer_windows(code, k, canonical)
+        window_ok = jnp.ones((cap, nw), bool)
+        for j in range(k):
+            window_ok = window_ok & base_ok[:, j:j + nw]
+    else:
+        # forward one-word codes, op for op as the k-mer cells' programs
+        # have them (tests/test_lowered_programs.py pins their text)
+        acc = jnp.zeros((cap, nw), jnp.int32)
+        window_ok = jnp.ones((cap, nw), bool)
+        for j in range(k):
+            acc = acc * 4 + code[:, j:j + nw]
+            window_ok = window_ok & base_ok[:, j:j + nw]
+        words = (acc,)
     in_len = jnp.arange(nw)[None, :] + k <= lens[:, None]
     ok = (window_ok & in_len & part.mask()[:, None]).reshape(-1)
     # compact valid k-mers to the front (partition count semantics)
     order = jnp.argsort(~ok, stable=True)
-    codes = jnp.take(acc.reshape(-1), order, mode="clip")
+    words = [jnp.take(w.reshape(-1), order, mode="clip") for w in words]
+    codes = words[0] if len(words) == 1 else jnp.stack(words, axis=1)
     total = jnp.sum(ok).astype(jnp.int32)
     ones = (jnp.arange(cap * nw) < total).astype(jnp.int32)
     return make_partition((codes, ones), total)
+
+
+# ---------------------------------------------------------------------------
+# kmer-histo: a k-mer table -> its spectrum (GenomeScope's `jellyfish histo`)
+# ---------------------------------------------------------------------------
+
+HISTO_MANIFEST = ImageManifest(
+    output_schema=Schema((field(jnp.int32), field(jnp.int32))),
+    out_capacity=PRESERVE,
+    # bins 0..high: a dense table downstream
+    key_space=lambda env: env["high"] + 1,
+    commands=(CommandSpec(
+        "kmer-histo", args=(ArgSpec("high", type=int, required=False),)),),
+    default_command="kmer-histo")
+
+
+@container_op("kmer-histo", manifest=HISTO_MANIFEST, high=10000)
+def kmer_histo(part: Partition, high: int = 10000, **kw: Any) -> Partition:
+    """``jellyfish histo``: map each record of a ``reduce_by_key`` output,
+    ``(key, values, count)``, to ``(min(count, high), 1)``.  Keyed on
+    the first leaf and summed (``reduce_by_key(sum)``, whose table of
+    ``high + 1`` bins the manifest's ``key_space`` declares), the ones
+    give the k-mer spectrum: how many distinct keys occur ``b`` times,
+    for ``b < high``, and ``high`` or more times in the last bin.
+    ``high`` comes from the param or the grammar (``kmer-histo 10000``).
+    """
+    if high < 1:
+        raise ValueError(f"kmer-histo needs high >= 1, got {high}")
+    counts = jax.tree.leaves(part.records)[-1]
+    bins = jnp.minimum(counts, high).astype(jnp.int32)
+    return make_partition((bins, part.mask().astype(jnp.int32)),
+                          part.count)
 
 
 # ---------------------------------------------------------------------------

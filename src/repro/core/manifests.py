@@ -93,7 +93,7 @@ class CommandSpec:
     output_schema: Any = _INHERIT            # Schema | callable | None
     out_capacity: Any = _INHERIT             # int | callable | PRESERVE
     monoid: Optional[str] = _INHERIT
-    key_space: Any = _INHERIT                # int | callable(env) -> int
+    key_space: Any = _INHERIT        # int | callable(env) -> int | None
     associative_commutative: Optional[bool] = None
 
     def parse(self, argv: List[str], image: str) -> Dict[str, Any]:
@@ -195,7 +195,7 @@ class Contract:
         ks = self.key_space
         if callable(ks):
             try:
-                return int(ks(env))
+                ks = ks(env)
             except KeyError:
                 return None
         return None if ks is None else int(ks)

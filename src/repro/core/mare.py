@@ -53,7 +53,7 @@ from repro.core.container import (ContainerOp, Registry, DEFAULT_REGISTRY)
 from repro.core.dataset import ShardedDataset
 from repro.core.mounts import Mount
 from repro.core.plan import (KEYED_MONOIDS, Plan, StageState, infer_stage,
-                             infer_states)
+                             infer_states, key_words)
 from repro.core.schema import schema_of_records
 from repro.deprecations import warn_once
 
@@ -374,44 +374,63 @@ class MaRe:
                       salt: int = 1) -> "MaRe":
         """Grouped aggregation: fold records with equal keys (lazy).
 
-        ``key_by(records) -> int array [capacity]`` computes a key per
-        record; keys must lie in ``[0, num_keys)`` (the bounded key table —
-        out-of-range keys raise ``RuntimeError`` at action time through
-        the same one-sync error channel as shuffle overflow).  When the
-        upstream image's manifest declares a ``key_space`` (e.g.
-        ``kmer-stats``: ``4**k``), ``num_keys`` may be omitted and is
-        inferred at plan time — and an explicit ``num_keys`` smaller than
-        the declared key space fails at *build* time.  ``value_by``
-        selects the value pytree to fold (default: the whole record
-        pytree); ``op`` is the merge monoid (``sum`` / ``max`` / ``min``,
-        associative+commutative by construction), or pass a container
-        spelling (``image="toolbox/sum"``, or ``image="ubuntu",
+        ``key_by(records)`` computes a key per record, and its form picks
+        the stage, statically, at build time:
+
+        * a one-word key (int ``[capacity]``) is **dense**: keys must lie
+          in ``[0, num_keys)`` (the bounded key table — out-of-range keys
+          raise ``RuntimeError`` at action time through the same one-sync
+          error channel as shuffle overflow).  When the upstream image's
+          manifest declares a ``key_space`` (e.g. ``kmer-stats``:
+          ``4**k`` for ``k <= 15``), ``num_keys`` may be omitted and is
+          inferred at plan time — and an explicit ``num_keys`` smaller
+          than the declared key space fails at *build* time;
+        * a two-word key (32-bit ints ``[capacity, 2]``, high then low,
+          e.g. ``kmer-stats`` codes for ``16 <= k <= 31``) is **sorted**:
+          there is no table and no ``num_keys`` (passing one is an
+          error; it is for one-word keys only), and ``op`` is ``sum``
+          over integer values.  Each shard sorts its records on the key
+          and sums each run of equal keys
+          (``repro.kernels.segment_reduce.sort_agg``); the output holds
+          one record per distinct key, in ascending key order, within a
+          capacity of the input records (times the shards on a mesh), and
+          ``report().diagnostics['stage<i>.distinct_keys']`` counts them.
+          On a mesh the records (or, with the combiner, each shard's
+          partials) go to the owner that a hash of both words picks, at
+          the shard's record capacity, so none can be dropped.
+
+        ``value_by`` selects the value pytree to fold (default: the whole
+        record pytree); ``op`` is the merge monoid (``sum`` / ``max`` /
+        ``min``, associative+commutative by construction), or pass a
+        container spelling (``image="toolbox/sum"``, or ``image="ubuntu",
         command="awk-sum"``) — the pulled image's *manifest* must declare
         the monoid, as in the paper's combiner listings.
 
         Execution fuses into the single program like every other stage:
         with ``combiner=True`` (default) each shard pre-aggregates per key
         **before** the hash exchange — the classic map-side combiner — so
-        shuffle volume scales with distinct keys, not records, and the
-        per-destination send capacity is the statically-known largest hash
-        bucket.  The result partition on each shard holds the keys hashing
-        to it as records ``(key, folded_values, record_count)``, compacted
-        to the front.  The segment-reduce hot path autotunes between the
-        tiled Pallas kernel and the fused/sorted/scatter jnp strategies
-        per shape (``use_kernel=True/False`` forces the kernel/the plain
-        scatter; ``REPRO_SEGMENT_KERNEL`` overrides the default; see
-        docs/kernels.md).
+        shuffle volume scales with distinct keys, not records, and a
+        dense stage's per-destination send capacity is the
+        statically-known largest hash bucket.  On one device there is no
+        exchange, and a keyed stage folds once whatever ``combiner`` says.
+        The result partition on each shard holds the keys hashing to it
+        as records ``(key, folded_values, record_count)``, compacted to
+        the front.  A dense stage's segment-reduce hot path autotunes
+        between the tiled Pallas kernel and the fused/sorted/scatter jnp
+        strategies per shape (``use_kernel=True/False`` forces the
+        kernel/the plain scatter; ``REPRO_SEGMENT_KERNEL`` overrides the
+        default; see docs/kernels.md).
 
-        Skew: with ``combiner=False`` a hot key inflates every shard's
-        statically-sized exchange buffer.  ``salt=S`` (S > 1) spreads
-        each key's records over S consecutive shards and re-exchanges
-        per-key partials in a second hop, shrinking buffers by ~S/2 on
-        hot-key data (docs/architecture.md §keyed exchange).  After any
-        action, ``report().diagnostics['stage<i>.max_send_count']`` is the
-        tightest lossless ``capacity=`` observed — the feedback knob if
-        the salted heuristic capacity ever overflows.  ``salt`` with
-        ``combiner=True`` is rejected: the combiner already bounds the
-        exchange by distinct keys, so salting could only add a hop.
+        Skew (dense stages): with ``combiner=False`` a hot key inflates
+        every shard's statically-sized exchange buffer.  ``salt=S`` (S >
+        1) spreads each key's records over S consecutive shards and
+        re-exchanges per-key partials in a second hop, shrinking buffers
+        by ~S/2 on hot-key data (docs/architecture.md §keyed exchange).
+        After any action, ``report().diagnostics['stage<i>.max_send_count']``
+        is the tightest lossless ``capacity=`` observed — the feedback
+        knob if the salted heuristic capacity ever overflows.  ``salt``
+        with ``combiner=True`` is rejected: the combiner already bounds
+        the exchange by distinct keys, so salting could only add a hop.
         """
         if image is not None:
             op = _resolve_monoid(image, command, self.registry)
@@ -425,8 +444,29 @@ class MaRe:
                 "salt > 1 requires combiner=False: the map-side combiner "
                 "already caps the exchange at one record per distinct key, "
                 "so hot-key splitting has nothing to spread")
+        state = self._stage_states()[-1]
+        if key_words(key_by, state) == 2:
+            if num_keys is not None:
+                raise ValueError(
+                    f"num_keys={num_keys} given for a two-word key "
+                    "([capacity, 2]): num_keys sizes the table of a "
+                    "one-word key only; a two-word key takes the sorted "
+                    "keyed stage, which needs none")
+            if salt > 1:
+                raise ValueError(
+                    "salt > 1 applies to a dense (one-word) key only: the "
+                    "sorted keyed stage exchanges at the shard's record "
+                    "capacity, which no hot key can overflow")
+            if op != "sum":
+                raise ValueError(
+                    f"op={op!r} over a two-word key: the sorted keyed "
+                    "stage folds op='sum' of integer values only")
+            return self._chain(self.plan.then_keyed_reduce(
+                key_by, op=op, num_keys=None, value_by=value_by,
+                combiner=combiner, capacity=capacity,
+                use_kernel=use_kernel))
         if num_keys is None:
-            num_keys = self._stage_states()[-1].key_space
+            num_keys = state.key_space
             if num_keys is None:
                 raise ValueError(
                     "num_keys not given and no upstream image manifest "

@@ -24,6 +24,7 @@ import numpy as np
 from repro.core.container import ContainerOp, Partition, make_partition
 from repro.core.manifests import PlanTypeError
 from repro.core.schema import Field, Schema, SchemaMismatch
+from repro.kernels.segment_reduce.sort_agg import check_integer_values
 from repro.obs import span
 
 
@@ -150,30 +151,41 @@ KEYED_MONOIDS = ("sum", "max", "min")
 class KeyedReduceStage:
     """Grouped aggregation: fold records with equal keys into one record.
 
-    ``key_by(records) -> int array [capacity]`` (vectorized keyBy); keys
-    must lie in ``[0, num_keys)`` — the bounded key table is the static-SPMD
-    price of sort-free aggregation, and out-of-range keys are counted into
-    the action-time error channel rather than silently dropped.
+    ``key_by(records)`` is a vectorized keyBy.  A one-word key (int
+    ``[capacity]``) is *dense*: keys must lie in ``[0, num_keys)`` — the
+    bounded key table is the static-SPMD price of sort-free aggregation,
+    and out-of-range keys are counted into the action-time error channel
+    rather than silently dropped.  A two-word key (32-bit ints
+    ``[capacity, 2]``, high then low) is *sorted* (``num_keys`` is
+    ``None``, ``op`` is ``sum`` of integer values): no table, the records
+    are sorted on the key and summed per run of equal keys
+    (``repro.kernels.segment_reduce.sort_agg``), so the output is bounded
+    by the records, not by the key space.
     ``value_by`` selects the value pytree to fold (default: the whole
     record).  With ``combiner=True`` each shard pre-aggregates its records
     per key *before* the exchange (the classic map-side combiner), so
     shuffle volume scales with distinct keys, not records.  With
-    ``combiner=False``, ``salt > 1`` splits hot keys over ``salt``
-    destination shards (round-robin by record slot) and re-exchanges the
-    per-key partials in a second, combiner-style hop — the skew defense
-    when one key dominates the raw record stream.
+    ``combiner=False``, ``salt > 1`` (dense only) splits hot keys over
+    ``salt`` destination shards (round-robin by record slot) and
+    re-exchanges the per-key partials in a second, combiner-style hop —
+    the skew defense when one key dominates the raw record stream.
     """
 
     kind: ClassVar[str] = "reduce_by_key"
 
     key_by: Callable[[Any], jax.Array]
     op: str
-    num_keys: int
+    num_keys: Optional[int]
     value_by: Optional[Callable[[Any], Any]] = None
     combiner: bool = True
     capacity: Optional[int] = None
     use_kernel: Optional[bool] = None
     salt: int = 1
+
+    @property
+    def sorted(self) -> bool:
+        """Whether the stage folds a two-word key by sorting (no table)."""
+        return self.num_keys is None
 
     def signature(self) -> Tuple:
         # key_by/value_by key on callable identity, like ShuffleStage.key_by
@@ -184,7 +196,8 @@ class KeyedReduceStage:
     def describe(self) -> str:
         comb = "on" if self.combiner else "off"
         extra = f", salt={self.salt}" if self.salt > 1 else ""
-        return (f"reduce_by_key[{self.op}, keys={self.num_keys}, "
+        keys = "sorted" if self.sorted else self.num_keys
+        return (f"reduce_by_key[{self.op}, keys={keys}, "
                 f"combiner={comb}{extra}]")
 
 
@@ -205,10 +218,17 @@ def stage_counter_kinds(stage: Stage) -> Tuple[str, ...]:
     have been lossless for this run — the runtime capacity-feedback knob.
     ``exchange_buffer_rows`` is the *static* per-shard exchange buffer
     allocation (rows) so skewed-vs-salted buffer volume is observable.
+    A sorted keyed stage has no key table, so no ``key_overflow``; its
+    ``distinct_keys`` is the distinct keys it output (summed over
+    shards: each key has one owner).
     """
     if isinstance(stage, ShuffleStage):
         return ("shuffle_dropped",)
     if isinstance(stage, KeyedReduceStage):
+        if stage.sorted:
+            return ("shuffle_dropped", "exchanged_records",
+                    "max_send_count", "exchange_buffer_rows",
+                    "distinct_keys")
         return ("key_overflow", "shuffle_dropped", "exchanged_records",
                 "max_send_count", "exchange_buffer_rows")
     return ()
@@ -237,7 +257,7 @@ class Plan:
         return Plan(stages=self.stages + (ReduceStage(op, depth),))
 
     def then_keyed_reduce(self, key_by: Callable[[Any], jax.Array],
-                          op: str, num_keys: int,
+                          op: str, num_keys: Optional[int],
                           value_by: Optional[Callable[[Any], Any]] = None,
                           combiner: bool = True,
                           capacity: Optional[int] = None,
@@ -389,10 +409,42 @@ def _infer_op(state: StageState, op: ContainerOp, stage_idx: int,
                       producer=label)
 
 
+def key_words(key_by, state: StageState) -> Optional[int]:
+    """How many 32-bit words ``key_by`` gives each record against the
+    inferred schema: 1 for an int ``[capacity]`` key, 2 for a 32-bit int
+    ``[capacity, 2]`` key (high, low), ``None`` where the schema is
+    unknown or the key has another form (:func:`_check_key_by` says
+    which)."""
+    if state.schema is None or state.capacity is None \
+            or not state.schema.concrete:
+        return None
+    try:
+        spec = jax.eval_shape(key_by, state.schema.structs(state.capacity))
+    except Exception:
+        return None
+    return _spec_words(spec, state.capacity)
+
+
+def _spec_words(spec, capacity: int) -> Optional[int]:
+    """:func:`key_words` of an abstract key ``spec``."""
+    leaves = jax.tree.leaves(spec)
+    if len(leaves) != 1 or not np.issubdtype(np.dtype(leaves[0].dtype),
+                                             np.integer):
+        return None
+    shape = tuple(leaves[0].shape)
+    if shape == (capacity,):
+        return 1
+    if shape == (capacity, 2) and leaves[0].dtype.itemsize == 4:
+        return 2
+    return None
+
+
 def _check_key_by(stage, state: StageState, stage_idx: int,
-                  what: str) -> None:
+                  what: str, words: int = 1) -> None:
     """Abstractly evaluate a keyBy against the inferred schema: it must
-    map the record pytree to an int array of one key per record."""
+    map the record pytree to an int array of one key per record, of
+    shape ``[capacity]`` (``words`` 1) or 32-bit ``[capacity, 2]``
+    (``words`` 2, a sorted keyed stage)."""
     if state.schema is None or state.capacity is None \
             or not state.schema.concrete:
         return
@@ -404,16 +456,14 @@ def _check_key_by(stage, state: StageState, stage_idx: int,
             f"stage {stage_idx} ({what}): key_by failed against inferred "
             f"schema {state.schema.describe()} (from {state.producer}): "
             f"{e}") from e
-    leaves = jax.tree.leaves(spec)
-    ok = (len(leaves) == 1
-          and np.issubdtype(np.dtype(leaves[0].dtype), np.integer)
-          and tuple(leaves[0].shape) == (state.capacity,))
-    if not ok:
+    if _spec_words(spec, state.capacity) != words:
+        leaves = jax.tree.leaves(spec)
         got = [(str(l.dtype), tuple(l.shape)) for l in leaves]
+        want = (f"one int array of shape [{state.capacity}]" if words == 1
+                else f"one 32-bit int array of shape [{state.capacity}, 2]")
         raise PlanTypeError(
-            f"stage {stage_idx} ({what}): key_by must return one int "
-            f"array of shape [{state.capacity}] over schema "
-            f"{state.schema.describe()}, got {got}")
+            f"stage {stage_idx} ({what}): key_by must return {want} "
+            f"over schema {state.schema.describe()}, got {got}")
 
 
 def _key_by_is_passthrough(key_by, state: StageState) -> bool:
@@ -445,14 +495,16 @@ def _key_by_is_passthrough(key_by, state: StageState) -> bool:
 def _infer_keyed(state: StageState, stage: "KeyedReduceStage",
                  stage_idx: int) -> StageState:
     label = f"stage {stage_idx} ({stage.describe()})"
-    if (state.key_space is not None and stage.num_keys < state.key_space
+    if (not stage.sorted and state.key_space is not None
+            and stage.num_keys < state.key_space
             and _key_by_is_passthrough(stage.key_by, state)):
         raise PlanTypeError(
             f"{label}: key table num_keys={stage.num_keys} is smaller "
             f"than the key space {state.key_space} declared by "
             f"{state.producer} — keys would overflow at action time; "
             f"raise num_keys (or omit it to infer {state.key_space})")
-    _check_key_by(stage, state, stage_idx, stage.describe())
+    _check_key_by(stage, state, stage_idx, stage.describe(),
+                  words=2 if stage.sorted else 1)
     out_schema = None
     if state.schema is not None and state.capacity is not None \
             and state.schema.concrete:
@@ -468,10 +520,26 @@ def _infer_keyed(state: StageState, stage: "KeyedReduceStage",
         value_fields = jax.tree.map(
             lambda l: Field(np.dtype(l.dtype).name,
                             tuple(int(d) for d in l.shape[1:])), values)
-        out_schema = Schema((Field("int32"), value_fields, Field("int32")))
-    return StageState(schema=out_schema, capacity=stage.num_keys,
-                      num_shards=state.num_shards,
-                      key_space=stage.num_keys, producer=label)
+        key_field = Field("int32")
+        if stage.sorted:
+            try:
+                check_integer_values(values)
+            except TypeError as e:
+                raise PlanTypeError(f"{label}: {e}") from e
+            key = jax.eval_shape(stage.key_by, structs)
+            key_field = Field(np.dtype(key.dtype).name, (2,))
+        out_schema = Schema((key_field, value_fields, Field("int32")))
+    if not stage.sorted:
+        return StageState(schema=out_schema, capacity=stage.num_keys,
+                          num_shards=state.num_shards,
+                          key_space=stage.num_keys, producer=label)
+    # one record a distinct key: at most the records a shard holds, or,
+    # across shards, every record any shard may send it
+    capacity = state.capacity
+    if state.num_shards > 1 and capacity is not None:
+        capacity = state.num_shards * (stage.capacity or capacity)
+    return StageState(schema=out_schema, capacity=capacity,
+                      num_shards=state.num_shards, producer=label)
 
 
 def infer_stage(stage: Stage, state: StageState, i: int) -> StageState:

@@ -40,11 +40,12 @@ from repro.core.container import Partition, make_partition
 from repro.core.dataset import ShardedDataset
 from repro.core.plan import (KeyedReduceStage, MapStage, Plan, ReduceStage,
                              ShuffleStage, _apply_chain, _IdKey)
-from repro.core.shuffle import (keyed_bucket_capacity, salted_dest,
-                                shuffle_partition)
+from repro.core.shuffle import (hash_key_words, keyed_bucket_capacity,
+                                salted_dest, shuffle_partition)
 from repro.core.tree_reduce import (keyed_combine_partition,
                                     keyed_merge_partition,
                                     tree_reduce_partition)
+from repro.kernels.segment_reduce.sort_agg import sort_aggregate
 
 
 #: A MaRe scope in an op's ``op_name`` metadata: the stage's
@@ -107,6 +108,9 @@ class CompiledProgram:
     #: stages were lowered for one device (``report().diagnostics``
     #: ``stage<i>.local_keyed``).
     local_keyed: Dict[int, int] = dataclasses.field(default_factory=dict)
+    #: :func:`sorted_keyed_stages` of the plan: which keyed stages fold a
+    #: two-word key by sorting (``stage<i>.sorted_keyed``).
+    sorted_keyed: Dict[int, int] = dataclasses.field(default_factory=dict)
     _aot: Optional[Callable[..., Tuple]] = None   # jax.stages.Compiled
     _scopes: Optional[Dict[str, str]] = None
 
@@ -153,8 +157,9 @@ class CompiledProgram:
         """
         if self._aot is not None:
             return
-        args = ({"local_keyed": {f"s{i}": v for i, v in
-                                 self.local_keyed.items()}}
+        args = ({name: {f"s{i}": v for i, v in stages.items()}
+                 for name, stages in (("local_keyed", self.local_keyed),
+                                      ("sorted_keyed", self.sorted_keyed))}
                 if self.local_keyed else {})
         with timed("plan.lower", phases, **args):
             lowered = self.fn.lower(records, counts)
@@ -288,6 +293,8 @@ def _apply_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
     this shard; max-reduced across shards by the executor),
     exchange_buffer_rows (static per-shard buffer allocation).
     """
+    if stage.sorted:
+        return _apply_sorted_keyed(stage, part, axis, axis_size)
     num_keys = stage.num_keys
     salt = 1 if stage.combiner else max(1, int(stage.salt))
     with jax.named_scope("combine"):
@@ -388,6 +395,68 @@ def _apply_keyed_local(stage: KeyedReduceStage, send: Partition,
                  exchanged, max_send, jnp.full((), buffer_rows, jnp.int32)]
 
 
+def _apply_sorted_keyed(stage: KeyedReduceStage, part: Partition, axis: str,
+                        axis_size: int) -> Tuple[Partition, List[jax.Array]]:
+    """Shard-interior sorted keyed stage (a two-word key, no table).
+
+    ``combine``: the records, with a count of 1 each, are summed per
+    distinct key by :func:`~repro.kernels.segment_reduce.sort_agg.
+    sort_aggregate` (on a mesh only with the combiner on; on one device
+    always, as the stage's one fold).  On a mesh, ``exchange`` sends each
+    record or partial to the owner that :func:`~repro.core.shuffle.
+    hash_key_words` picks, at the shard's record capacity (``capacity=``
+    if given), so no hot key can overflow it, and ``merge`` folds what
+    arrived, partial sums and counts alike.  One device lowers neither
+    (:func:`_apply_keyed_local`'s reasoning).  The output is one record
+    ``(key [2], values, count)`` a distinct key, ascending, compacted to
+    the front.
+
+    Counters (order = ``stage_counter_kinds``): shuffle_dropped,
+    exchanged_records, max_send_count, exchange_buffer_rows and
+    distinct_keys (the records this shard output).
+    """
+    with jax.named_scope("combine"):
+        keys = stage.key_by(part.records)
+        values = (stage.value_by(part.records) if stage.value_by is not None
+                  else part.records)
+        valid = part.mask()
+        ones = valid.astype(jnp.int32)
+        if stage.combiner or axis_size == 1:
+            agg = sort_aggregate(keys, values, ones, valid)
+            send = make_partition((agg.keys, agg.values, agg.counts),
+                                  agg.distinct)
+        else:
+            send = make_partition((keys, values, ones), part.count)
+    cap = stage.capacity or send.capacity
+    if axis_size == 1:
+        sent = jnp.minimum(send.count, cap).astype(jnp.int32)
+        out = make_partition(send.records, sent)
+        return out, [(send.count - sent).astype(jnp.int32), sent, sent,
+                     jnp.full((), cap, jnp.int32), sent]
+    with jax.named_scope("exchange"):
+        dest = (hash_key_words(send.records[0])
+                % jnp.uint32(axis_size)).astype(jnp.int32)
+        res = shuffle_partition(send, send.records[0], axis_name=axis,
+                                axis_size=axis_size, capacity=cap,
+                                dest=dest)
+        exchanged = jnp.sum(res.send_counts).astype(jnp.int32)
+        max_send = jnp.max(res.send_counts).astype(jnp.int32)
+    with jax.named_scope("merge"):
+        rkeys, rvalues, rcounts = res.part.records
+        agg = sort_aggregate(rkeys, rvalues, rcounts, res.part.mask())
+        out = make_partition((agg.keys, agg.values, agg.counts),
+                             agg.distinct)
+    return out, [res.dropped.astype(jnp.int32), exchanged, max_send,
+                 jnp.full((), axis_size * cap, jnp.int32), out.count]
+
+
+def sorted_keyed_stages(plan: Plan) -> Dict[int, int]:
+    """``{i: 1 or 0}`` for each keyed stage ``i`` of ``plan``: 1 where it
+    folds a two-word key by sorting (:func:`_apply_sorted_keyed`)."""
+    return {i: int(st.sorted) for i, st in enumerate(plan.stages)
+            if isinstance(st, KeyedReduceStage)}
+
+
 def local_keyed_stages(plan: Plan, axis_size: int) -> Dict[int, int]:
     """``{i: 1 or 0}`` for each keyed stage ``i`` of ``plan``: 1 where
     :func:`_apply_keyed` lowers it without the exchange, which is where
@@ -476,7 +545,7 @@ def _plan_uses_pallas(plan: Plan) -> bool:
     answers "is tiled in the candidate set" (TPU backend or a forced
     kernel), not "will tiled win"."""
     from repro.kernels.segment_reduce.ops import resolve_use_kernel
-    return any(isinstance(st, KeyedReduceStage)
+    return any(isinstance(st, KeyedReduceStage) and not st.sorted
                and resolve_use_kernel(st.use_kernel, st.op)
                for st in plan.stages)
 
@@ -506,7 +575,8 @@ def compile_plan(plan: Plan, ds: ShardedDataset,
         return CompiledProgram(fn=fn, counters=counters, key=key,
                                name=name,
                                local_keyed=local_keyed_stages(plan,
-                                                              axis_size))
+                                                              axis_size),
+                               sorted_keyed=sorted_keyed_stages(plan))
 
     return cache.get_or_compile(key, build, phases=phases)
 

@@ -33,6 +33,13 @@ def hash_keys(keys: jax.Array) -> jax.Array:
     return x
 
 
+def hash_key_words(keys: jax.Array) -> jax.Array:
+    """The HashPartitioner of a two-word key (``[n, 2]``, high then low):
+    :func:`hash_keys` of the low word mixed with the hash of the high
+    one, so keys that differ in either word spread.  Returns uint32."""
+    return hash_keys(keys[:, 1].astype(jnp.uint32) ^ hash_keys(keys[:, 0]))
+
+
 def keyed_bucket_capacities(num_keys: int, axis_size: int) -> np.ndarray:
     """Exact per-destination bucket sizes of the keyed hash exchange.
 

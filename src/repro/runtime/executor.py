@@ -120,8 +120,11 @@ def execute(ds: ShardedDataset, plan: Plan, *,
     debugging and benchmarking.  ``diagnostics``, when given, is filled
     with per-counter totals keyed ``"stage<i>.<kind>"``, and for each
     keyed stage ``"stage<i>.local_keyed"``: 1 where the program lowered
-    it for one device, without the exchange (a host-side fact of the
-    compiled program, not a device counter); ``phases``,
+    it for one device, without the exchange, and
+    ``"stage<i>.sorted_keyed"``: 1 where it folds a two-word key by
+    sorting (host-side facts of the compiled program, not device
+    counters; a sorted stage's device counter ``distinct_keys`` sums
+    like ``exchanged_records``); ``phases``,
     when given, accumulates the per-phase wall breakdown (lower /
     compile / dispatch / device_wait / counter_sync) that
     :class:`~repro.runtime.reports.ActionReport.phases` surfaces.
@@ -138,6 +141,8 @@ def execute(ds: ShardedDataset, plan: Plan, *,
     if diagnostics is not None:
         for i, local in prog.local_keyed.items():
             diagnostics[f"stage{i + stage_offset}.local_keyed"] = local
+        for i, by_sort in prog.sorted_keyed.items():
+            diagnostics[f"stage{i + stage_offset}.sorted_keyed"] = by_sort
     # AOT split: lowering + XLA compile become their own phases/spans
     # (zero on a plan-cache hit) instead of hiding in the first dispatch
     prog.ensure_compiled(ds.records, ds.counts, phases)

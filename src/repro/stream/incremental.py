@@ -185,11 +185,12 @@ class IncrementalQuery:
             raise TypeError(f"build must return a MaRe chain, got "
                             f"{type(m).__name__}")
         plan = m.plan
-        if plan.empty or not isinstance(plan.stages[-1], KeyedReduceStage):
+        if (plan.empty or not isinstance(plan.stages[-1], KeyedReduceStage)
+                or plan.stages[-1].sorted):
             raise ValueError(
-                "an IncrementalQuery plan must end in reduce_by_key — "
-                "only a monoid-folded keyed table is incrementally "
-                f"maintainable (got plan [{plan.describe()}])")
+                "an IncrementalQuery plan must end in a dense (one-word "
+                "key) reduce_by_key — only a monoid-folded keyed table is "
+                f"incrementally maintainable (got plan [{plan.describe()}])")
         if self._plan_sig is None:
             self._plan = plan
             self._plan_sig = plan.signature()
