@@ -220,8 +220,8 @@ def kmer_stats(part: Partition, k: int = 8, canonical: bool = False,
         for j in range(k):
             window_ok = window_ok & base_ok[:, j:j + nw]
     else:
-        # forward one-word codes, op for op as the k-mer cells' programs
-        # have them (tests/test_lowered_programs.py pins their text)
+        # forward one-word codes: the k-mer cells' programs, whose text
+        # tests/test_lowered_programs.py pins
         acc = jnp.zeros((cap, nw), jnp.int32)
         window_ok = jnp.ones((cap, nw), bool)
         for j in range(k):
@@ -230,9 +230,16 @@ def kmer_stats(part: Partition, k: int = 8, canonical: bool = False,
         words = (acc,)
     in_len = jnp.arange(nw)[None, :] + k <= lens[:, None]
     ok = (window_ok & in_len & part.mask()[:, None]).reshape(-1)
-    # compact valid k-mers to the front (partition count semantics)
-    order = jnp.argsort(~ok, stable=True)
-    words = [jnp.take(w.reshape(-1), order, mode="clip") for w in words]
+    # compact valid k-mers to the front (partition count semantics) with
+    # one sort that carries the words, as a gather of each word through an
+    # argsort's order costs several times the sort on a TPU.  The key is
+    # the invalid flag over the window's index: valid windows first, each
+    # side in order, and no two keys tie, so the sort need not be stable
+    # (a stable one carries an index of its own, in time and code)
+    key = ((~ok).astype(jnp.uint32) << 31) | jnp.arange(ok.size,
+                                                        dtype=jnp.uint32)
+    words = jax.lax.sort((key, *(w.reshape(-1) for w in words)),
+                         num_keys=1, is_stable=False)[1:]
     codes = words[0] if len(words) == 1 else jnp.stack(words, axis=1)
     total = jnp.sum(ok).astype(jnp.int32)
     ones = (jnp.arange(cap * nw) < total).astype(jnp.int32)
